@@ -183,7 +183,9 @@ def measure_parallel_campaign(epochs: int, wait_ms: float = 1.0) -> dict:
     can demonstrate the executor's overlap; the committed row measures
     exactly that.  Parallel workers keep per-worker memo caches, so they
     lose the cross-island dedup hits the serial memo gets — the measured
-    speedup is net of that (honest, not best-case).
+    speedup is net of that (honest, not best-case).  Fitness runs on the
+    host (`np`), so the workers are pinned to the CPU and the row obeys
+    the one-process-per-chip rule on a TPU too.
     """
     from repro.evolve.problems import ProblemSpec
 

@@ -75,6 +75,7 @@ from benchmarks.common import QUICK, get_trained_tnn
 from repro.core.tnn import exact_netlists
 from repro.compile.ir import lower_classifier
 from repro.compile.program import CircuitProgram
+from repro.runtime import on_tpu
 from repro.serve.engine import CircuitServingEngine
 
 BATCH_SIZES = (1, 64, 1024)
@@ -566,7 +567,13 @@ def run() -> list[dict]:
 
     n_fleet = 2048 if QUICK else 16384
     rows.extend(_measure_fleet(n_fleet))
-    rows.extend(_measure_workers(n_fleet))
+    if on_tpu():
+        # one process per chip: the swar tenants cannot dispatch from
+        # worker children there (the fleet refuses it), so the row is out
+        print("serve_workers: skipped on a TPU (device backends cannot "
+              "run in worker processes)")
+    else:
+        rows.extend(_measure_workers(n_fleet))
     rows.extend(_measure_megakernel(n_fleet))
     rows.extend(_measure_qos())
     rows.extend(_measure_socket("serve_socket", n_fleet, SOCKET_BATCH))

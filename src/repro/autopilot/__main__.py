@@ -320,4 +320,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

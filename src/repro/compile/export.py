@@ -72,4 +72,6 @@ def main(dataset: str = "breast_cancer", out_dir: str = "artifacts",
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     main(*sys.argv[1:3])

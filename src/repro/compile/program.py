@@ -35,9 +35,8 @@ class CircuitProgram:
 
     `backend` picks the executor: ``np`` is the uint64 `Netlist` reference;
     ``swar`` (alias ``jax``, the historical name) and ``pallas`` route
-    through `kernels.dispatch.program_eval_words`, which shards large
-    batches along the packed-word axis across `devices` (default: all
-    local devices).
+    through `kernels.dispatch.program_eval_words`, which runs each batch
+    on `devices` (a 1-tuple; default: the default device).
     """
 
     ir: CircuitIR
@@ -45,11 +44,9 @@ class CircuitProgram:
     n_classes: int | None = None
     backend: str = "jax"
     devices: tuple | None = None
-    # Pallas tuning knobs (word-tile width / interpret-mode override);
-    # forwarded to the kernel on the pallas backend, ignored elsewhere so
-    # configs can set them unconditionally
+    # Pallas word-tile width; forwarded to the kernel on the pallas
+    # backend, ignored elsewhere so configs can set it unconditionally
     pallas_block_words: int | None = None
-    pallas_interpret: bool | None = None
     _netlist: C.Netlist | None = field(default=None, repr=False)
     _jax_plan: tuple | None = field(default=None, repr=False)
 
@@ -130,8 +127,7 @@ class CircuitProgram:
         out = D.program_eval_words(op, in0, in1, outs, words32,
                                    self.ir.n_inputs, backend=exec_backend,
                                    devices=self.devices,
-                                   block_words=self.pallas_block_words,
-                                   interpret=self.pallas_interpret)
+                                   block_words=self.pallas_block_words)
         return np.asarray(out[0], dtype=np.int64)
 
     # -- classifier inference ----------------------------------------------

@@ -18,7 +18,8 @@ missing bundle, or a corrupt one (checksum mismatch) rebuilds that entry
 alone.  ``--force`` rebuilds everything.
 
 Entries are independent, so the sweep fans out over a spawned worker
-pool (``--workers``).  Workers compile and emit files only
+pool (``--workers``; refused on a TPU, whose chip belongs to one
+process — build serially there).  Workers compile and emit files only
 (``write_artifacts(register=False)``): the ``fleet.json`` manifest is
 read-modify-write, so the parent registers the returned rows serially —
 no manifest races, deterministic generation numbering.
@@ -211,6 +212,11 @@ def build_zoo(entries: list[ZooEntry], emit_dir: str | Path,
             import multiprocessing as mp
             from concurrent.futures import ProcessPoolExecutor
 
+            from repro.runtime import refuse_device_children
+            refuse_device_children(f"zoo workers={workers}",
+                                   "workers=1 (entries compile in this "
+                                   "process)")
+
             with ProcessPoolExecutor(
                     max_workers=min(workers, len(pending)),
                     mp_context=mp.get_context("spawn")) as pool:
@@ -307,4 +313,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     main()

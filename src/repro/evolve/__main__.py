@@ -177,4 +177,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     main()

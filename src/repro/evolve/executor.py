@@ -22,6 +22,10 @@ process (spawn initializer) — TNN problems ride the content-addressed
 phase cache, so a worker boot costs a cache load, not a retrain — and
 keep their own bounded `_memoized` cache across tasks and epochs.
 
+One process per chip: workers evaluate fitness on the host (`np`), pinned
+to the CPU.  A device `eval_backend` would need the chip in every child,
+so on a TPU the executor refuses it; step serially (`workers` 0) there.
+
 Pinned by tests/test_evolve.py: identical archive X/F arrays and island
 histories across 1/2/4 workers, and the executor path survives the
 existing SIGKILL-resume tests (checkpointing is unchanged — the parent
@@ -38,6 +42,7 @@ from repro.core.nsga2 import (NSGA2Driver, NSGA2State, _memoized,
                               decode_rng_state, encode_rng_state)
 from repro.evolve.config import CampaignConfig
 from repro.evolve.problems import ProblemSpec, build_problem
+from repro.runtime import pin_to_cpu, refuse_device_children
 
 # Per-worker-process globals, installed by `_worker_init` (spawn context:
 # each worker imports fresh, so this dict is private per process).
@@ -59,7 +64,15 @@ def _unpack_state(t: tuple) -> NSGA2State:
                       history=[tuple(h) for h in history])
 
 
+def _eval_backends(spec: ProblemSpec, cfg: CampaignConfig) -> set:
+    """Every fitness backend a worker would run: the campaign's and, for
+    a TNN problem, its objective's."""
+    return {cfg.eval_backend, spec.kwargs.get("eval_backend", "np")}
+
+
 def _worker_init(spec: ProblemSpec, cfg: CampaignConfig) -> None:
+    if _eval_backends(spec, cfg) == {"np"}:
+        pin_to_cpu()
     problem = build_problem(spec)
     evaluate = (_memoized(problem.objective, maxsize=cfg.memo_maxsize)
                 if cfg.base.dedup_eval else problem.objective)
@@ -133,6 +146,12 @@ class IslandExecutor:
                             "process boundary)")
         import multiprocessing as mp
 
+        backends = _eval_backends(spec, cfg)
+        if backends != {"np"}:
+            refuse_device_children(
+                f"the island executor with eval_backend "
+                f"{'/'.join(sorted(backends))}",
+                "workers=0 (serial stepping in this process)")
         self.n_workers = int(n_workers or cfg.workers or
                              min(cfg.n_islands, os.cpu_count() or 1))
         if self.n_workers < 1:

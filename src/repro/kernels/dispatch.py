@@ -8,14 +8,17 @@ population x packed-word gate-simulation hot loop:
   * ``swar``   — the jitted `lax.scan` uint32-SWAR twin in
     `kernels.circuit_sim` (the PR 1 device path / benchmark baseline);
   * ``pallas`` — the Pallas kernel in `kernels.pallas_circuit_sim`
-    (compiled on TPU, interpret-mode elsewhere).
+    (compiled on a TPU, interpret mode on the CPU).
 
-Device sharding: for the device backends the population axis is split
-round-even across `jax.local_devices()` (or an explicit device list) —
-fitness rows are independent, so each device simulates its slice of
-genomes against the (shared or per-individual) word plane and results
-concatenate on host.  On this container that degenerates to a single CPU
-device; the split logic is identical for an 8-chip pod.
+Device placement: for a population the rows are split round-even across
+`jax.local_devices()` (or an explicit device list) — fitness rows are
+independent, so each device simulates its slice of genomes against the
+(shared or per-individual) word plane and results concatenate on host.
+A serving dispatch (`program_eval_words`) is one program over one batch
+and runs on ONE device: the replica's pinned device, else the default
+device.  Its word axis is never split — a 256-reading batch is 8 words,
+and cutting it into per-chip shards would only add launches and host
+syncs; a fleet spreads load over chips with replicas instead.
 
 This lives in `kernels` (not `repro.evolve`) so consumers below the
 orchestration layer — e.g. `core.tnn.TNNApproxProblem` — can select a
@@ -64,9 +67,9 @@ def replica_devices(index: int, devices=None) -> tuple:
     overlap across chips instead of queueing on one; the returned 1-tuple
     plugs straight into `CircuitProgram(devices=...)`, whose
     `program_eval_words` treats any explicit device list as a pinning
-    request (device_put even for a single shard).  On this single-device
-    container every replica pins to the same CPU device — the round-robin
-    is identical on an 8-chip pod.
+    request: the replica's whole batch runs on that device.  With one
+    device every replica pins to it; on a four-chip host replicas 0-3
+    land on chips 0-3.
     """
     if index < 0:
         raise ValueError("replica index must be >= 0")
@@ -84,18 +87,13 @@ def _device_slices(P: int, n_dev: int) -> list[slice]:
     return [slice(s, min(s + per, P)) for s in range(0, P, per)]
 
 
-def _pallas_kwargs(block_words, interpret) -> dict:
+def _pallas_kwargs(block_words) -> dict:
     """Only non-default Pallas knobs, so jit static-arg caches stay warm."""
-    kw = {}
-    if block_words is not None:
-        kw["block_words"] = int(block_words)
-    if interpret is not None:
-        kw["interpret"] = bool(interpret)
-    return kw
+    return {} if block_words is None else {"block_words": int(block_words)}
 
 
 def _eval_device(op, in0, in1, outputs, packed_u64, n_inputs, backend,
-                 devices, block_words=None, interpret=None) -> np.ndarray:
+                 devices, block_words=None) -> np.ndarray:
     import jax
 
     from repro.kernels import circuit_sim as CS
@@ -104,7 +102,7 @@ def _eval_device(op, in0, in1, outputs, packed_u64, n_inputs, backend,
 
         from repro.kernels import pallas_circuit_sim as PS
         eval_fn = partial(PS.population_eval_uint,
-                          **_pallas_kwargs(block_words, interpret))
+                          **_pallas_kwargs(block_words))
     else:
         eval_fn = CS.population_eval_uint
     words32 = CS.pack_words32(packed_u64)
@@ -126,18 +124,16 @@ def _eval_device(op, in0, in1, outputs, packed_u64, n_inputs, backend,
 def population_eval_uint(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
                          outputs: np.ndarray, packed_u64: np.ndarray,
                          n_inputs: int, backend: str = "swar",
-                         devices=None, block_words=None,
-                         interpret=None) -> np.ndarray:
+                         devices=None, block_words=None) -> np.ndarray:
     """Per-vector decoded outputs `(P, S)` for a population of netlists.
 
     `packed_u64` is `(n_inputs, W)` shared or `(P, n_inputs, W)`
     per-individual uint64 words; every backend returns the same integers
     for the same words (rows are `Netlist.eval_uint` of the row's genome).
 
-    `block_words` / `interpret` are Pallas tuning knobs (word-tile width
-    and interpret-mode override) forwarded to
+    `block_words` is the Pallas word-tile width, forwarded to
     `pallas_circuit_sim.population_eval_uint`; the other backends ignore
-    them, so campaign/tenant configs can set them unconditionally.
+    it, so campaign/tenant configs can set it unconditionally.
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown eval backend {backend!r}; "
@@ -153,36 +149,30 @@ def population_eval_uint(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
                         np.asarray(in1, dtype=np.int32),
                         np.asarray(outputs, dtype=np.int32),
                         packed_u64, n_inputs, backend, devices,
-                        block_words=block_words,
-                        interpret=interpret).astype(np.int64)
+                        block_words=block_words).astype(np.int64)
 
 
 def population_eval_pop(pop: NetlistPopulation, packed_u64: np.ndarray,
                         backend: str = "swar", devices=None,
-                        block_words=None, interpret=None) -> np.ndarray:
+                        block_words=None) -> np.ndarray:
     """`population_eval_uint` over an existing `NetlistPopulation`."""
     return population_eval_uint(pop.op, pop.in0, pop.in1, pop.outputs,
                                 packed_u64, pop.n_inputs, backend=backend,
-                                devices=devices, block_words=block_words,
-                                interpret=interpret)
+                                devices=devices, block_words=block_words)
 
 
 def program_eval_words(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
                        outputs: np.ndarray, words32: np.ndarray,
                        n_inputs: int, backend: str = "swar",
-                       devices=None, block_words=None,
-                       interpret=None) -> np.ndarray:
+                       devices=None, block_words=None) -> np.ndarray:
     """Single-program serving dispatch: `(n_inputs, W)` uint32 words ->
     `(P, W*32)` int64 decoded outputs, on any backend.
 
-    The population twin of `population_eval_uint` shards the *population*
-    axis; a serving engine runs one program (P=1 plan rows) over a large
-    batch, so here the independent axis is the packed *word* plane — for
-    the device backends large batches split round-even along the word axis
-    across `jax.local_devices()` (or an explicit device list) and results
-    concatenate on host.  `repro.serve` pins each fleet tenant's dispatches
-    through this entry point, so a tenant maps to `np`/`swar`/`pallas`
-    exactly like a campaign evaluator does.
+    The device backends run the whole batch on one device: `devices`, a
+    1-tuple, pins it there (a fleet replica's device); None leaves it on
+    the default device.  `repro.serve` pins each fleet tenant's
+    dispatches through this entry point, so a tenant maps to
+    `np`/`swar`/`pallas` exactly like a campaign evaluator does.
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown eval backend {backend!r}; "
@@ -219,32 +209,23 @@ def program_eval_words(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
 
         from repro.kernels import pallas_circuit_sim as PS
         eval_fn = partial(PS.population_eval_uint,
-                          **_pallas_kwargs(block_words, interpret))
+                          **_pallas_kwargs(block_words))
     else:
         eval_fn = CS.population_eval_uint
     plan = (np.asarray(op, dtype=np.int32), np.asarray(in0, dtype=np.int32),
             np.asarray(in1, dtype=np.int32),
             np.asarray(outputs, dtype=np.int32))
-    # an explicit device list is a pinning request even when it yields a
-    # single shard — only the implicit all-local-devices default may skip
-    # the device_put and run wherever jit places it
-    pinned = devices is not None
-    devices = list(devices) if pinned else jax.local_devices()
-    W = words32.shape[1]
-    slices = (_device_slices(W, len(devices)) if len(devices) > 1
-              else [slice(0, W)])
-    outs = []
-    for sl, dev in zip(slices, devices):
-        shard = words32[:, sl]
-        if pinned or len(slices) > 1:
-            shard = jax.device_put(shard, dev)
-        outs.append(np.asarray(eval_fn(*plan, shard, n_inputs)))
-    out = np.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
-    return out.astype(np.int64)
+    if devices is not None:
+        devices = tuple(devices)
+        if len(devices) != 1:
+            raise ValueError(f"a serving dispatch runs on one device; got "
+                             f"{len(devices)}")
+        words32 = jax.device_put(words32, devices[0])
+    return np.asarray(eval_fn(*plan, words32, n_inputs)).astype(np.int64)
 
 
 def fleet_eval_words(plans: list, words_list: list, backend: str = "pallas",
-                     block_words=None, interpret=None) -> list[np.ndarray]:
+                     block_words=None) -> list[np.ndarray]:
     """Whole-manifest serving dispatch: T tenants' circuits in ONE launch.
 
     `plans` holds one `(op, in0, in1, outputs, n_inputs)` plan tuple per
@@ -255,6 +236,7 @@ def fleet_eval_words(plans: list, words_list: list, backend: str = "pallas",
     grid over (tenant x word-tile), one `pallas_call` for the manifest.
     ``np``/``swar`` fall back to per-tenant `program_eval_words` loops
     (same answers, T launches), so callers can flip backends freely.
+    The launch runs on the default device.
 
     Returns one `(W_t * 32,)` int64 decoded-label array per tenant,
     bit-identical to dispatching each tenant through
@@ -266,7 +248,7 @@ def fleet_eval_words(plans: list, words_list: list, backend: str = "pallas",
     if backend == "pallas":
         from repro.kernels import pallas_circuit_sim as PS
         outs = PS.fleet_eval_words(plans, words_list,
-                                   **_pallas_kwargs(block_words, interpret))
+                                   **_pallas_kwargs(block_words))
         return [np.asarray(o, dtype=np.int64) for o in outs]
     outs = []
     for (op, in0, in1, outputs, n_in), w in zip(plans, words_list):

@@ -1,33 +1,39 @@
-"""Pallas kernels: population-parallel gate-level circuit simulation.
+"""Pallas kernel: population-parallel gate-level circuit simulation.
 
 The campaign hot loop — (population of genomes) x (packed test words) —
-as real Pallas kernels instead of the `lax.scan` SWAR twin in
+as a Pallas kernel instead of the `lax.scan` SWAR twin in
 `kernels/circuit_sim.py`.  Three entry points share one kernel body:
 
   * `simulate_population` — output *words* `(P, n_out, W)`, the
     conformance-suite surface (bit-identical to both host evaluators);
-  * `fused_eval_uint` — the **fused megakernel**: gate walk, output-word
-    extraction and LSB-first integer decode in ONE `pallas_call`.  The
-    value plane never leaves VMEM and the per-output-bit `(P, W, 32)`
-    planes the old two-stage path materialized in HBM are gone — each
-    grid cell writes its decoded int32 tile directly;
+  * `fused_eval_uint` — gate walk, output-word extraction and LSB-first
+    integer decode in ONE `pallas_call`: the value plane never leaves
+    VMEM and each grid cell writes its decoded int32 tile directly;
   * `fleet_eval_words` — the **multi-program megakernel**: T tenants'
-    plan tables padded to a common gate budget and paged into VMEM, grid
-    over (tenant x word-tile), so a serving fleet evaluates its whole
-    manifest in one launch instead of per-tenant batches.
+    plan tables padded to a common gate budget, grid over
+    (tenant x word-tile), so a serving fleet evaluates its whole manifest
+    in one launch instead of per-tenant batches.
 
-Grid layout for the fused kernel is (population tiles, word tiles): each
-program instance owns a `block_pop`-row slab of plan tables and a
-`block_words`-wide slab of packed uint32 test words, walks the gate
-columns with a `fori_loop` over a VMEM-resident value plane of shape
-`(block_pop, n_inputs + n_gates, block_words)`, and writes that tile's
-decoded integers.  Word tiles stream through the grid — Pallas
-double-buffers the per-tile DMA behind the gate walk automatically, so
-HBM traffic for the word plane overlaps compute.  Gates apply through the
-same algebraic normal form r = m0 ^ (ma & a) ^ (mb & b) ^ (mab & (a & b))
-as both existing evaluators, with the per-gate coefficient masks
-precomputed on the host — the kernel body is branch-free regardless of
-opcode mix.
+Grid layout is (population rows, word tiles).  Each program instance
+owns one population row (one genome, or one tenant) and a `bw`-wide tile
+of packed test words:
+
+  * the row's plan — gate taps and the per-gate ANF coefficient masks —
+    arrives as a `(1, 6, G)` int32 **SMEM** block, so every gate reads
+    its operands' node indices as scalars;
+  * the value plane is a `(n_inputs + G, bw)` int32 VMEM scratch; gate g
+    reads rows `in0[g]`/`in1[g]` and writes row `n_inputs + g` with
+    `pl.ds`, a dynamic *sublane* index, which Mosaic lowers;
+  * `bw` is either the whole word axis or a multiple of 128 lanes;
+  * the decode writes a `(32, bw)` tile (row s = bit s of every word)
+    and the jitted wrapper lays the tiles out as `(P, W*32)`.
+
+Gates apply through the same algebraic normal form
+r = m0 ^ (ma & a) ^ (mb & b) ^ (mab & (a & b)) as both host evaluators,
+with the masks precomputed on the host, so the body is branch-free
+regardless of opcode mix.  Words travel as int32 (a bitcast of the
+uint32 lanes): every operation on them is bitwise, so the bits are
+unchanged.
 
 Bit-compatibility contract (pinned by tests/test_conformance.py):
 identical output words to `NetlistPopulation.simulate` (lane-split via
@@ -38,10 +44,8 @@ integer, and the fleet kernel matches per-tenant dispatch on every
 tenant regardless of gate-count/feature-count/output-width skew
 (padding must never leak into outputs).
 
-On TPU the plan rows stay resident in VMEM and the word axis streams
-through the grid; off-TPU the kernels run in interpret mode (the
-repo-wide dispatch policy, cf. `kernels/ops.py`), where the population
-tiling keeps the XLA program shape close to the SWAR scan.
+The kernel compiles on a TPU and runs in interpret mode on the CPU; on
+any other platform it refuses to run (`_interpret`).
 """
 from __future__ import annotations
 
@@ -53,274 +57,185 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.circuit_sim import (_C0_TBL, _CA_TBL, _CAB_TBL, _CB_TBL,
-                                       _U32)
+from repro.kernels.circuit_sim import _C0_TBL, _CA_TBL, _CAB_TBL, _CB_TBL
 
 DEFAULT_BLOCK_WORDS = 128
-DEFAULT_BLOCK_POP = 8
+LANES = 128
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret mode on the CPU, compiled on a TPU, an error elsewhere."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(f"the Pallas circuit kernel runs compiled on a TPU or "
+                       f"interpreted on the CPU, not on {platform!r}")
 
 
-def _pad_gateless(op, in0, in1):
-    """Zero-size blocks are illegal in pallas_call — pad gateless plans
-    with one dead CONST0 gate (node n_inputs, unreachable by outputs)."""
-    from repro.hw.egfet import Gate
-    P = op.shape[0]
-    op = np.full((P, 1), int(Gate.CONST0), dtype=np.int16)
-    in0 = np.zeros((P, 1), dtype=np.int32)
-    in1 = np.zeros((P, 1), dtype=np.int32)
-    return op, in0, in1
+def _word_tile(W: int, block_words: int | None) -> int:
+    """Word-tile width: the whole word axis, or a multiple of 128 lanes."""
+    bw = DEFAULT_BLOCK_WORDS if block_words is None else int(block_words)
+    if bw <= 0 or bw % LANES:
+        raise ValueError(f"block_words must be a positive multiple of "
+                         f"{LANES}, got {block_words}")
+    return W if W <= bw else bw
 
 
-def _kernel(in0_ref, in1_ref, m0_ref, ma_ref, mb_ref, mab_ref, out_idx_ref,
-            words_ref, out_ref, vals_ref, *, n_inputs: int, n_gates: int,
-            n_out: int):
-    # blocks: plan rows (1, G) int32 / uint32; words (n_inputs, bw) or
-    # (1, n_inputs, bw) uint32; out (1, n_out, bw); vals scratch
-    # (n_inputs + G, bw) uint32.
-    w = words_ref[...]
-    vals_ref[pl.ds(0, n_inputs), :] = w.reshape(n_inputs, -1)
-    if n_gates:
-        vals_ref[pl.ds(n_inputs, n_gates), :] = jnp.zeros(
-            (n_gates, w.shape[-1]), dtype=_U32)
+def _kernel(plan_ref, outputs_ref, words_ref, out_ref, vals_ref, *,
+            n_inputs: int, n_gates: int, n_out: int, decode: bool):
+    # plan_ref (1, 6, G) and outputs_ref (1, 1, n_out) int32 in SMEM;
+    # words_ref (n_inputs, bw) shared or (1, n_inputs, bw) per-row;
+    # vals_ref (n_inputs + G, bw) int32 VMEM scratch; out_ref (1, 32, bw)
+    # decoded or (1, n_out, bw) output words.
+    bw = vals_ref.shape[1]
+    words = words_ref[0] if len(words_ref.shape) == 3 else words_ref[...]
+    vals_ref[pl.ds(0, n_inputs), :] = words
+    vals_ref[pl.ds(n_inputs, n_gates), :] = jnp.zeros((n_gates, bw),
+                                                      jnp.int32)
 
     def body(g, carry):
-        a = vals_ref[pl.ds(in0_ref[0, g], 1), :]
-        b = vals_ref[pl.ds(in1_ref[0, g], 1), :]
-        r = (m0_ref[0, g] ^ (ma_ref[0, g] & a) ^ (mb_ref[0, g] & b)
-             ^ (mab_ref[0, g] & (a & b)))
+        a = vals_ref[pl.ds(plan_ref[0, 0, g], 1), :]
+        b = vals_ref[pl.ds(plan_ref[0, 1, g], 1), :]
+        r = (plan_ref[0, 2, g] ^ (plan_ref[0, 3, g] & a)
+             ^ (plan_ref[0, 4, g] & b) ^ (plan_ref[0, 5, g] & (a & b)))
         vals_ref[pl.ds(n_inputs + g, 1), :] = r
         return carry
 
-    if n_gates:
-        jax.lax.fori_loop(0, n_gates, body, 0)
-    for o in range(n_out):           # n_out is static and small (<= 8)
-        out_ref[0, pl.ds(o, 1), :] = vals_ref[pl.ds(out_idx_ref[0, o], 1), :]
+    jax.lax.fori_loop(0, n_gates, body, 0)
+    if decode:
+        # LSB-first: vector s of word w is bit (s % 32), so tile row s
+        # collects bit s of every word, one output bit per integer bit
+        shifts = jax.lax.broadcasted_iota(jnp.int32, (32, bw), 0)
+        acc = jnp.zeros((32, bw), jnp.int32)
+        for o in range(n_out):                 # n_out is static and small
+            row = vals_ref[pl.ds(outputs_ref[0, 0, o], 1), :]
+            acc = acc | ((jax.lax.shift_right_logical(row, shifts) & 1) << o)
+        out_ref[0] = acc
+    else:
+        for o in range(n_out):
+            out_ref[0, pl.ds(o, 1), :] = vals_ref[
+                pl.ds(outputs_ref[0, 0, o], 1), :]
 
 
-@partial(jax.jit,
-         static_argnames=("n_inputs", "block_words", "interpret"))
-def _simulate_padded(in0, in1, m0, ma, mb, mab, outputs, words32, *,
-                     n_inputs: int, block_words: int, interpret: bool):
-    P, G = in0.shape
-    n_out = outputs.shape[1]
+@partial(jax.jit, static_argnames=("n_inputs", "block_words", "decode",
+                                   "interpret"))
+def _fused_padded(plan, outputs, words32, *, n_inputs: int,
+                  block_words: int, decode: bool, interpret: bool):
+    """`plan` (P, 6, G) int32, `outputs` (P, 1, n_out) int32, `words32`
+    (n_inputs, Wp) or (P, n_inputs, Wp) uint32 with Wp a multiple of
+    `block_words`.  Returns (P, Wp*32) int32 decoded integers, or
+    (P, n_out, Wp) uint32 output words when `decode` is False."""
+    P, _, G = plan.shape
+    n_out = outputs.shape[2]
     Wp = words32.shape[-1]
-    shared = words32.ndim == 2
-    grid = (P, Wp // block_words)
-    words_spec = (pl.BlockSpec((n_inputs, block_words), lambda p, w: (0, w))
-                  if shared else
-                  pl.BlockSpec((1, n_inputs, block_words),
-                               lambda p, w: (p, 0, w)))
-    plan_spec = pl.BlockSpec((1, G), lambda p, w: (p, 0))
-    return pl.pallas_call(
-        partial(_kernel, n_inputs=n_inputs, n_gates=G, n_out=n_out),
-        grid=grid,
-        in_specs=[plan_spec, plan_spec, plan_spec, plan_spec, plan_spec,
-                  plan_spec,
-                  pl.BlockSpec((1, n_out), lambda p, w: (p, 0)),
+    bw = block_words
+    words = jax.lax.bitcast_convert_type(words32, jnp.int32)
+    words_spec = (pl.BlockSpec((n_inputs, bw), lambda p, w: (0, w))
+                  if words.ndim == 2 else
+                  pl.BlockSpec((1, n_inputs, bw), lambda p, w: (p, 0, w)))
+    rows = 32 if decode else n_out
+    out = pl.pallas_call(
+        partial(_kernel, n_inputs=n_inputs, n_gates=G, n_out=n_out,
+                decode=decode),
+        grid=(P, Wp // bw),
+        in_specs=[pl.BlockSpec((1, 6, G), lambda p, w: (p, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, 1, n_out), lambda p, w: (p, 0, 0),
+                               memory_space=pltpu.SMEM),
                   words_spec],
-        out_specs=pl.BlockSpec((1, n_out, block_words),
-                               lambda p, w: (p, 0, w)),
-        out_shape=jax.ShapeDtypeStruct((P, n_out, Wp), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((n_inputs + G, block_words), jnp.uint32)],
+        out_specs=pl.BlockSpec((1, rows, bw), lambda p, w: (p, 0, w)),
+        out_shape=jax.ShapeDtypeStruct((P, rows, Wp), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((n_inputs + G, bw), jnp.int32)],
         interpret=interpret,
-    )(in0, in1, m0, ma, mb, mab, outputs, words32)
+    )(plan, outputs, words)
+    if decode:
+        return out.transpose(0, 2, 1).reshape(P, Wp * 32)
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)
+
+
+def _plan_table(op, in0, in1) -> np.ndarray:
+    """(P, G) opcodes + operand taps -> the kernel's (P, 6, G) int32 plan:
+    rows in0, in1 and the four ANF coefficient masks (as int32 bits).
+
+    A gateless plan gets one dead CONST0 gate (zero-size blocks are
+    illegal in pallas_call); outputs never tap it."""
+    op = np.asarray(op, dtype=np.int64)
+    in0 = np.asarray(in0, dtype=np.int32)
+    in1 = np.asarray(in1, dtype=np.int32)
+    if op.shape[1] == 0:
+        from repro.hw.egfet import Gate
+        P = op.shape[0]
+        op = np.full((P, 1), int(Gate.CONST0), dtype=np.int64)
+        in0 = in1 = np.zeros((P, 1), dtype=np.int32)
+    masks = [tbl[op].view(np.int32) for tbl in (_C0_TBL, _CA_TBL, _CB_TBL,
+                                                 _CAB_TBL)]
+    return np.ascontiguousarray(np.stack([in0, in1, *masks], axis=1))
+
+
+def _run(op, in0, in1, outputs, words32, n_inputs: int, *,
+         block_words: int | None, decode: bool) -> jax.Array:
+    plan = _plan_table(op, in0, in1)
+    outputs = np.asarray(outputs, dtype=np.int32)[:, None, :]
+    words32 = jnp.asarray(words32, dtype=jnp.uint32)
+    W = words32.shape[-1]
+    bw = _word_tile(W, block_words)
+    wpad = (-W) % bw
+    if wpad:
+        pad_width = [(0, 0)] * (words32.ndim - 1) + [(0, wpad)]
+        words32 = jnp.pad(words32, pad_width)
+    return _fused_padded(jnp.asarray(plan), jnp.asarray(outputs), words32,
+                         n_inputs=n_inputs, block_words=bw, decode=decode,
+                         interpret=_interpret())
 
 
 def simulate_population(op, in0, in1, outputs, words32, n_inputs: int, *,
-                        block_words: int = DEFAULT_BLOCK_WORDS,
-                        interpret: bool | None = None) -> jax.Array:
+                        block_words: int | None = None) -> jax.Array:
     """Pallas twin of `circuit_sim.simulate_population`.
 
     op/in0/in1: (P, G) int; outputs: (P, n_out) int; words32: (n_inputs, W)
     shared or (P, n_inputs, W) per-individual uint32 words.  Returns
     (P, n_out, W) uint32, bit-identical to both existing evaluators.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
-    op = np.asarray(op)
-    P = op.shape[0]
-    n_out = np.asarray(outputs).shape[1]
-    W = np.asarray(words32).shape[-1]
+    P = np.shape(op)[0]
+    n_out = np.shape(outputs)[1]
+    W = np.shape(words32)[-1]
     if W == 0:
-        # a zero-width word plane has nothing to simulate — mirror the
-        # gateless-plan pad guard instead of handing pallas_call a
-        # zero-size grid/block (which it rejects)
+        # a zero-width word plane has nothing to simulate: no zero-size
+        # grid or block may reach pallas_call
         return jnp.zeros((P, n_out, 0), dtype=jnp.uint32)
-    if op.shape[1] == 0:
-        op, in0, in1 = _pad_gateless(op, in0, in1)
-    m0 = _C0_TBL[op]                   # (P, G) uint32 ANF masks
-    ma = _CA_TBL[op]
-    mb = _CB_TBL[op]
-    mab = _CAB_TBL[op]
-    in0 = jnp.asarray(np.asarray(in0, dtype=np.int32))
-    in1 = jnp.asarray(np.asarray(in1, dtype=np.int32))
-    outputs = jnp.asarray(np.asarray(outputs, dtype=np.int32))
-    words32 = jnp.asarray(words32, dtype=jnp.uint32)
-    bw = min(block_words, max(W, 1))
-    pad = (-W) % bw
-    if pad:
-        pad_width = ([(0, 0), (0, pad)] if words32.ndim == 2
-                     else [(0, 0), (0, 0), (0, pad)])
-        words32 = jnp.pad(words32, pad_width)
-    out = _simulate_padded(in0, in1, jnp.asarray(m0), jnp.asarray(ma),
-                           jnp.asarray(mb), jnp.asarray(mab), outputs,
-                           words32, n_inputs=n_inputs, block_words=bw,
-                           interpret=interpret)
+    out = _run(op, in0, in1, outputs, words32, n_inputs,
+               block_words=block_words, decode=False)
     return out[:, :, :W]
 
 
-# ---------------------------------------------------------------------------
-# Fused megakernel: gate walk + output extraction + LSB-first decode in one
-# pallas_call.  Grid is (population tiles, word tiles); the value plane for
-# a (block_pop, block_words) tile lives in VMEM for the whole gate walk and
-# the decoded int32 tile is written directly — no (P, n_out, W) word plane
-# and no per-output-bit (P, W, 32) planes ever reach HBM.
-# ---------------------------------------------------------------------------
-def _fused_kernel(in0_ref, in1_ref, m0_ref, ma_ref, mb_ref, mab_ref,
-                  out_idx_ref, words_ref, out_ref, *, n_inputs: int,
-                  n_gates: int, n_out: int, block_pop: int, shared: bool):
-    bp = block_pop
-    w = words_ref[...]                      # (n_inputs, bw) | (bp, n_in, bw)
-    bw = w.shape[-1]
-    inw = (jnp.broadcast_to(w.reshape(1, n_inputs, bw), (bp, n_inputs, bw))
-           if shared else w.reshape(bp, n_inputs, bw))
-    vals = jnp.zeros((bp, n_inputs + n_gates, bw), dtype=_U32)
-    vals = jax.lax.dynamic_update_slice_in_dim(vals, inw, 0, axis=1)
-
-    def body(g, vals):
-        i0 = in0_ref[:, pl.ds(g, 1)]        # (bp, 1) per-individual taps
-        i1 = in1_ref[:, pl.ds(g, 1)]
-        a = jnp.take_along_axis(vals, i0[:, :, None], axis=1)[:, 0]
-        b = jnp.take_along_axis(vals, i1[:, :, None], axis=1)[:, 0]
-        r = (m0_ref[:, pl.ds(g, 1)] ^ (ma_ref[:, pl.ds(g, 1)] & a)
-             ^ (mb_ref[:, pl.ds(g, 1)] & b)
-             ^ (mab_ref[:, pl.ds(g, 1)] & (a & b)))
-        return jax.lax.dynamic_update_slice_in_dim(
-            vals, r[:, None, :], n_inputs + g, axis=1)
-
-    if n_gates:
-        vals = jax.lax.fori_loop(0, n_gates, body, vals)
-    outs = out_idx_ref[...]                 # (bp, n_out)
-    outw = jnp.take_along_axis(vals, outs[:, :, None], axis=1)
-    # LSB-first decode, fused: vector s of word w is bit (s % 32), so the
-    # (bp, bw, 32) bit cube reshapes straight into the per-vector ints
-    shifts = jnp.arange(32, dtype=_U32)
-    acc = jnp.zeros((bp, bw, 32), dtype=jnp.int32)
-    for o in range(n_out):                  # n_out is static and small
-        bits = ((outw[:, o, :, None] >> shifts) & _U32(1)).astype(jnp.int32)
-        acc = acc + (bits << o)
-    out_ref[...] = acc.reshape(bp, bw * 32)
-
-
-@partial(jax.jit, static_argnames=("n_inputs", "block_words", "block_pop",
-                                   "interpret"))
-def _fused_padded(in0, in1, m0, ma, mb, mab, outputs, words32, *,
-                  n_inputs: int, block_words: int, block_pop: int,
-                  interpret: bool):
-    Pp, G = in0.shape
-    n_out = outputs.shape[1]
-    Wp = words32.shape[-1]
-    shared = words32.ndim == 2
-    bp, bw = block_pop, block_words
-    grid = (Pp // bp, Wp // bw)
-    words_spec = (pl.BlockSpec((n_inputs, bw), lambda p, w: (0, w))
-                  if shared else
-                  pl.BlockSpec((bp, n_inputs, bw), lambda p, w: (p, 0, w)))
-    plan_spec = pl.BlockSpec((bp, G), lambda p, w: (p, 0))
-    return pl.pallas_call(
-        partial(_fused_kernel, n_inputs=n_inputs, n_gates=G, n_out=n_out,
-                block_pop=bp, shared=shared),
-        grid=grid,
-        in_specs=[plan_spec, plan_spec, plan_spec, plan_spec, plan_spec,
-                  plan_spec,
-                  pl.BlockSpec((bp, n_out), lambda p, w: (p, 0)),
-                  words_spec],
-        out_specs=pl.BlockSpec((bp, bw * 32), lambda p, w: (p, w)),
-        out_shape=jax.ShapeDtypeStruct((Pp, Wp * 32), jnp.int32),
-        interpret=interpret,
-    )(in0, in1, m0, ma, mb, mab, outputs, words32)
-
-
 def fused_eval_uint(op, in0, in1, outputs, words32, n_inputs: int, *,
-                    block_words: int | None = None,
-                    block_pop: int | None = None,
-                    interpret: bool | None = None) -> jax.Array:
+                    block_words: int | None = None) -> jax.Array:
     """Fused gate-walk + decode: `(P, W*32)` int32 in one `pallas_call`.
 
     Bit-identical to `circuit_sim.population_eval_uint` (and therefore to
     decoding `simulate_population`'s words on the host), for shared and
     per-individual word planes.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
-    if block_words is None:
-        block_words = DEFAULT_BLOCK_WORDS
-    op = np.asarray(op)
-    P = op.shape[0]
-    W = np.asarray(words32).shape[-1]
+    P = np.shape(op)[0]
+    W = np.shape(words32)[-1]
     if W == 0:
         return jnp.zeros((P, 0), dtype=jnp.int32)
-    if op.shape[1] == 0:
-        op, in0, in1 = _pad_gateless(op, in0, in1)
-    m0 = _C0_TBL[op]
-    ma = _CA_TBL[op]
-    mb = _CB_TBL[op]
-    mab = _CAB_TBL[op]
-    in0 = np.asarray(in0, dtype=np.int32)
-    in1 = np.asarray(in1, dtype=np.int32)
-    outputs = np.asarray(outputs, dtype=np.int32)
-    words32 = jnp.asarray(words32, dtype=jnp.uint32)
-    bp = min(block_pop if block_pop is not None else DEFAULT_BLOCK_POP,
-             max(P, 1))
-    bw = min(block_words, max(W, 1))
-    wpad = (-W) % bw
-    if wpad:
-        pad_width = ([(0, 0), (0, wpad)] if words32.ndim == 2
-                     else [(0, 0), (0, 0), (0, wpad)])
-        words32 = jnp.pad(words32, pad_width)
-    ppad = (-P) % bp
-    if ppad:
-        # pad plan rows with copies of row 0 — cheap, always well-formed,
-        # and the padded rows are sliced off below
-        idx = np.concatenate([np.arange(P), np.zeros(ppad, dtype=np.int64)])
-        in0, in1 = in0[idx], in1[idx]
-        m0, ma, mb, mab = m0[idx], ma[idx], mb[idx], mab[idx]
-        outputs = outputs[idx]
-        if words32.ndim == 3:
-            words32 = jnp.concatenate(
-                [words32, jnp.repeat(words32[:1], ppad, axis=0)], axis=0)
-    out = _fused_padded(jnp.asarray(in0), jnp.asarray(in1), jnp.asarray(m0),
-                        jnp.asarray(ma), jnp.asarray(mb), jnp.asarray(mab),
-                        jnp.asarray(outputs), words32, n_inputs=n_inputs,
-                        block_words=bw, block_pop=bp, interpret=interpret)
-    return out[:P, : W * 32]
+    out = _run(op, in0, in1, outputs, words32, n_inputs,
+               block_words=block_words, decode=True)
+    return out[:, : W * 32]
 
 
-def population_eval_uint(op, in0, in1, outputs, words32, n_inputs: int, *,
-                         block_words: int | None = None,
-                         block_pop: int | None = None,
-                         interpret: bool | None = None) -> jax.Array:
-    """Decode output words (LSB-first) into per-vector ints: (P, W*32) int32.
-
-    Routed through the fused megakernel — one launch, no intermediate
-    output-word plane (the old two-stage path built an extra `(P, W, 32)`
-    plane per output bit on the host side of the kernel).
-    """
-    return fused_eval_uint(op, in0, in1, outputs, words32, n_inputs,
-                           block_words=block_words, block_pop=block_pop,
-                           interpret=interpret)
+population_eval_uint = fused_eval_uint
 
 
 # ---------------------------------------------------------------------------
 # Multi-program megakernel: T tenants' plans padded to one gate budget,
 # grid over (tenant x word-tile), one launch for the whole manifest.
 # ---------------------------------------------------------------------------
-def fleet_eval_words(plans, words_list, *, block_words: int | None = None,
-                     interpret: bool | None = None) -> list[np.ndarray]:
+def fleet_eval_words(plans, words_list, *,
+                     block_words: int | None = None) -> list[np.ndarray]:
     """Evaluate T single-program circuits over T word planes in ONE launch.
 
     `plans` is a list of `(op, in0, in1, outputs, n_inputs)` tuples —
@@ -383,7 +298,6 @@ def fleet_eval_words(plans, words_list, *, block_words: int | None = None,
         out_t[t, : outputs.shape[0]] = remap(outputs, n_in)
         words_t[t, :n_in, : w.shape[1]] = w
 
-    out = np.asarray(fused_eval_uint(
-        op_t, in0_t, in1_t, out_t, words_t, n_in_max,
-        block_words=block_words, block_pop=1, interpret=interpret))
+    out = np.asarray(fused_eval_uint(op_t, in0_t, in1_t, out_t, words_t,
+                                     n_in_max, block_words=block_words))
     return [out[t, : W_list[t] * 32] for t in range(T)]

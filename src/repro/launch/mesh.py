@@ -5,38 +5,23 @@ touches jax device state.  Single pod: 16x16 = 256 chips (v5e pod),
 axes (data, model).  Multi-pod: 2 pods = 512 chips, axes (pod, data, model)
 — the "pod" axis is the slow DCI dimension; batch shards over (pod, data).
 
-`jax.sharding.AxisType` only exists on jax >= 0.5; on the pinned 0.4.37 the
-`axis_types=` kwarg is unsupported, so `make_mesh_compat` transparently drops
-it (every axis is then implicitly "auto", which is the behaviour we rely on).
-Tests and launch code must build meshes through this shim, never through
-`jax.make_mesh(..., axis_types=...)` directly.
+Every axis is `AxisType.Auto`: the compiler propagates shardings from the
+annotated arrays, which is the behaviour the model code relies on.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # jax 0.4.x: no explicit/auto axis types, all axes auto
-    _AxisType = None
-
-HAS_AXIS_TYPES = _AxisType is not None
-
-
-def make_mesh_compat(shape, axis_names):
-    """`jax.make_mesh` with all-auto axis types where the API supports them."""
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(_AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke paths (same axis names, all size 1)."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -47,6 +47,7 @@ ANF_OPS_PER_GATE_WORD = 6     # r = m0 ^ (ma&a) ^ (mb&b) ^ (mab&(a&b))
 _PLAN_BYTES_PER_GATE = 4 + 4 + 4 * 4   # in0 + in1 + four uint32 ANF masks
 _WORD = 4                     # uint32
 _INT = 4                      # int32 decoded outputs
+_WORD_TILE = 128              # pallas_circuit_sim.DEFAULT_BLOCK_WORDS
 
 
 @dataclass
@@ -102,10 +103,12 @@ def pallas_unfused_roofline(s: CircuitShape) -> Roofline:
                     collective_bytes=0.0)
 
 
-def pallas_fused_roofline(s: CircuitShape, block_pop: int = 8) -> Roofline:
-    # one launch: a shared word plane is re-streamed once per pop tile,
-    # and the only output is the decoded int plane
-    tiles = max(1, -(-s.P // block_pop)) if s.shared_words else 1
+def pallas_fused_roofline(s: CircuitShape) -> Roofline:
+    # one launch: the grid walks one population row at a time; a shared
+    # word plane that spans several 128-word tiles is re-streamed once per
+    # row (a single tile stays resident), and the only output is the
+    # decoded int plane
+    tiles = s.P if s.shared_words and s.W > _WORD_TILE else 1
     byt = s._plan_bytes() + tiles * s._words_bytes() \
         + s.P * s.vectors * _INT
     return Roofline(flops=s.ops, bytes_accessed=float(byt),
@@ -131,15 +134,15 @@ def fleet_roofline(shapes: list[CircuitShape]) -> tuple[Roofline, float]:
                           n_out=n_out_max, shared_words=False)
     real_ops = sum(s.ops for s in shapes)
     eff = real_ops / padded.ops if padded.ops else 1.0
-    return pallas_fused_roofline(padded, block_pop=1), eff
+    return pallas_fused_roofline(padded), eff
 
 
-def variant_rows(s: CircuitShape, block_pop: int = 8) -> list[dict]:
+def variant_rows(s: CircuitShape) -> list[dict]:
     """One BENCH-ready row per single-program kernel variant."""
     rows = []
     for name, rl in (("swar", swar_roofline(s)),
                      ("pallas_unfused", pallas_unfused_roofline(s)),
-                     ("pallas_fused", pallas_fused_roofline(s, block_pop))):
+                     ("pallas_fused", pallas_fused_roofline(s))):
         rows.append({
             "variant": name,
             "ops": rl.flops,

@@ -70,7 +70,9 @@ def _add_fleet_args(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--workers", type=int, default=None,
                     help="process-per-backend dispatch workers: run N "
                          "subprocesses per backend fed over shared-memory "
-                         "reading planes (default: dispatch in-process)")
+                         "reading planes (default: dispatch in-process; "
+                         "refused for device backends on a TPU, whose "
+                         "chip belongs to one process)")
     ap.add_argument("--qos", default=None,
                     help="QoS classes: one of guaranteed|best_effort for "
                          "every tenant, or per-tenant pairs "
@@ -88,8 +90,9 @@ def _add_fleet_args(ap: argparse.ArgumentParser) -> None:
                          "launch per scheduler pass (in-process only; "
                          "non-pallas tenants dispatch normally)")
     ap.add_argument("--block-words", type=int, default=None,
-                    help="pallas word-tile width override (per-tenant "
-                         "dispatch AND the fused megakernel launch)")
+                    help="pallas word-tile width override, a multiple of "
+                         "128 (per-tenant dispatch AND the fused "
+                         "megakernel launch)")
     ap.add_argument("--autoscale", action="store_true",
                     help="grow/shrink replica pools from shed/queue/cost "
                          "pressure (bounds: --min-replicas/--max-replicas)")
@@ -619,4 +622,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

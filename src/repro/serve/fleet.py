@@ -47,7 +47,9 @@ each backend gets N spawned subprocesses holding their own engines, fed
 through a ring of shared-memory reading planes (`serve/workers.py`).
 Scheduling, admission, stats and completion all stay here; only
 `classify_batch` crosses the process boundary, so np/swar/pallas
-dispatch runs on real cores instead of sharing this process's GIL.
+dispatch runs on real cores instead of sharing this process's GIL.  On a
+TPU a chip belongs to one process, so device backends refuse worker
+processes there; serve them in-process with `replicas=N`.
 
 **Hot reload**: a fleet built by `from_emit_dir` can `sync_manifest()` at
 any time — new manifest rows become tenants, rows whose generation

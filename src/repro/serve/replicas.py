@@ -6,9 +6,9 @@ devices the host had.  A `ReplicaPool` runs N engines over the *same*
 compiled classifier behind the tenant's one micro-batch queue: the fleet
 scheduler acquires the least-loaded idle replica for each due batch, so
 two due batches of the same tenant overlap on different replicas (each
-pinned to its own local device via `kernels.dispatch.replica_devices` —
-the word-axis sharding in `program_eval_words` is the intra-dispatch half
-of that story, this pool is the inter-dispatch half).
+pinned to its own local device via `kernels.dispatch.replica_devices`;
+one dispatch runs whole on its replica's device, so the pool is how a
+tenant's load spreads over several chips).
 
 The pick policy is pure bookkeeping with no threads or clocks in it —
 `acquire`/`release` mutate integer counters under whatever lock the

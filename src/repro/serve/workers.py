@@ -51,6 +51,8 @@ from multiprocessing.connection import wait as _wait_ready
 
 import numpy as np
 
+from repro.runtime import DEVICE_BACKENDS, refuse_device_children
+
 DEFAULT_SLAB_BYTES = 1 << 20
 _CTX = get_context("spawn")     # fleet process has threads; fork is unsafe
 
@@ -75,15 +77,21 @@ def _attach_slab(name: str) -> _shm.SharedMemory:
         return _shm.SharedMemory(name=name)
 
 
-def _worker_main(wid: int, n_procs: int, task_q, result_c) -> None:
+def _worker_main(wid: int, n_procs: int, backend: str, task_q,
+                 result_c) -> None:
     """Worker child entry point (module-level: spawn must pickle it).
 
     Ops arrive as tuples on the dedicated task queue; every op that has a
     `seq` answers on this worker's own result pipe as ``("ack"|"ok"|"err",
     wid, seq, payload)``.  Engines import lazily so an np-only worker
-    never pays the jax import.
+    never pays the jax import, and an np worker is pinned to the CPU: it
+    does host work only and must never claim an accelerator.
     """
     from repro.kernels.dispatch import configure_worker_process
+    from repro.runtime import DEVICE_BACKENDS, pin_to_cpu
+
+    if backend not in DEVICE_BACKENDS:
+        pin_to_cpu()
     configure_worker_process(n_procs)
 
     from repro.compile.program import CircuitProgram
@@ -217,14 +225,15 @@ class _Pending:
 
 
 class _Proc:
-    def __init__(self, wid: int, n_procs: int):
+    def __init__(self, wid: int, n_procs: int, backend: str):
         self.wid = wid
         self.task_q = _CTX.Queue()
         # single writer per pipe: this worker's death can only tear its
         # own reply channel, never another worker's
         self.result_r, result_w = _CTX.Pipe(duplex=False)
         self.process = _CTX.Process(
-            target=_worker_main, args=(wid, n_procs, self.task_q, result_w),
+            target=_worker_main,
+            args=(wid, n_procs, backend, self.task_q, result_w),
             daemon=True)
         self.outstanding = 0
         self.failed = False     # reply pipe tore; reap even if still alive
@@ -258,6 +267,10 @@ class WorkerHost:
                  eval_timeout_s: float = 180.0):
         if n_procs < 1:
             raise ValueError("worker host needs at least one process")
+        if backend in DEVICE_BACKENDS:
+            refuse_device_children(
+                f"workers={n_procs} on the {backend} backend",
+                "in-process dispatch (workers=None) with replicas=N")
         self.backend = backend
         self.n_procs = n_procs
         self.eval_timeout_s = eval_timeout_s
@@ -278,7 +291,7 @@ class WorkerHost:
     # -- lifecycle ---------------------------------------------------------
 
     def start(self) -> None:
-        self._procs = [_Proc(i, self.n_procs)
+        self._procs = [_Proc(i, self.n_procs, self.backend)
                        for i in range(self.n_procs)]
         self._collector = threading.Thread(
             target=self._collect, name=f"workers-{self.backend}", daemon=True)
@@ -480,7 +493,7 @@ class WorkerHost:
                 orphans.extend(mine)
                 self.n_respawns += 1
                 self.n_errors += len(mine)
-                self._procs[i] = _Proc(wid, self.n_procs)
+                self._procs[i] = _Proc(wid, self.n_procs, self.backend)
                 for key, blob in self._tenants.items():
                     seq = self._next_seq()
                     # nobody waits on the reload ack; bookkeeping only

@@ -112,13 +112,13 @@ def test_numpy_restore_preserves_wide_dtypes(tmp_path):
 def test_elastic_restore_to_mesh(tmp_path):
     """Restore re-device_puts with the current (1-device) mesh sharding —
     the same code path reshards onto any topology."""
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    from repro.launch.mesh import make_mesh_compat
     cm = CheckpointManager(str(tmp_path))
     state = _state()
     cm.save(3, state)
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     specs = {"params": {"w": P("data", "model"), "b": P(None)},
              "opt": {"mu": P("data", None), "step": P()}}
     step, restored, _ = cm.restore(jax.tree.map(jnp.zeros_like, state),

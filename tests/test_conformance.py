@@ -6,7 +6,7 @@ vectors):
   1. `Netlist.simulate` / `eval_uint` — the serial uint64 reference,
   2. `NetlistPopulation` — structure-of-arrays batched numpy,
   3. `kernels.circuit_sim.simulate_population` — jitted uint32-SWAR scan,
-  4. `kernels.pallas_circuit_sim` — the Pallas kernel (interpret off-TPU),
+  4. `kernels.pallas_circuit_sim` — the Pallas kernel (interpret on CPU),
   5. `CircuitProgram` (jax + np backends) over the lowered `CircuitIR`,
 plus the emitted-Verilog route: `compile.verilog.emit_netlist_module` ->
 `compile.vread.VerilogDesign`, an evaluator that never sees the IR.
@@ -155,7 +155,9 @@ def test_block_words_knob_reaches_pallas_kernel(monkeypatch):
     """Regression (PR 9): dispatch used to silently drop the Pallas knobs
     — a campaign/tenant `block_words` override never reached the kernel.
     Pin the plumbing end-to-end by spying on the jitted pallas_call
-    wrapper through `program_eval_words` AND `population_eval_uint`."""
+    wrapper through `program_eval_words` AND `population_eval_uint`.
+    The word tile is the whole word axis or a multiple of 128 lanes, so
+    the plane here is 700 words wide and every override tiles it."""
     from repro.kernels import dispatch as D
 
     seen = []
@@ -168,23 +170,31 @@ def test_block_words_knob_reaches_pallas_kernel(monkeypatch):
     monkeypatch.setattr(PS, "_fused_padded", spy)
     rng = np.random.default_rng(11)
     pop = C.random_netlist_population(rng, 5, 12, 2, 4)
-    bits = _rand_bits(rng, 200, 5)          # 7 words — default tile is 128
+    bits = _rand_bits(rng, 700 * 32, 5)     # 700 words — default tile 128
     words32 = np.asarray(CS.pack_bits32(bits))
+    ref = pop.eval_uint(C.pack_vectors(bits))
 
-    D.program_eval_words(pop.op[:1], pop.in0[:1], pop.in1[:1],
-                         pop.outputs[:1], words32, 5, backend="pallas",
-                         block_words=2)
-    assert seen[-1] == 2, "block_words override never reached the kernel"
+    got = D.program_eval_words(pop.op[:1], pop.in0[:1], pop.in1[:1],
+                               pop.outputs[:1], words32, 5,
+                               backend="pallas", block_words=256)
+    assert seen[-1] == 256, "block_words override never reached the kernel"
+    np.testing.assert_array_equal(got[0], ref[0])
 
-    D.population_eval_uint(pop.op, pop.in0, pop.in1, pop.outputs,
-                           C.pack_vectors(bits), 5, backend="pallas",
-                           block_words=3)
-    assert seen[-1] == 3
+    got = D.population_eval_uint(pop.op, pop.in0, pop.in1, pop.outputs,
+                                 C.pack_vectors(bits), 5, backend="pallas",
+                                 block_words=384)
+    assert seen[-1] == 384
+    np.testing.assert_array_equal(got, ref)
 
     prog = CircuitProgram.from_netlist(pop.netlist(0), backend="pallas",
-                                       pallas_block_words=4)
+                                       pallas_block_words=512)
     prog.eval_bits(bits)
-    assert seen[-1] == 4, "CircuitProgram.pallas_block_words was dropped"
+    assert seen[-1] == 512, "CircuitProgram.pallas_block_words was dropped"
+
+    with pytest.raises(ValueError, match="multiple of 128"):
+        D.program_eval_words(pop.op[:1], pop.in0[:1], pop.in1[:1],
+                             pop.outputs[:1], words32, 5, backend="pallas",
+                             block_words=2)
 
 
 def test_np_backend_odd_width_repack_matches_swar():

@@ -43,8 +43,9 @@ def test_elastic_restore_across_topologies(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import CheckpointManager
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         w = jax.device_put(jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
                            NamedSharding(mesh, P("data", "model")))
         cm = CheckpointManager({str(tmp_path)!r})
@@ -57,8 +58,9 @@ def test_elastic_restore_across_topologies(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import CheckpointManager
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4, 1), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cm = CheckpointManager({str(tmp_path)!r})
         step, state, _ = cm.restore({{"w": jnp.zeros((8, 8), jnp.float32)}},
                                     mesh=mesh,
