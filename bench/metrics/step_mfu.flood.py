@@ -1,0 +1,6 @@
+"""Whole step's share of the int8 peak, serving flood cells, %."""
+from harness.readers import step_mfu
+
+
+def read(run: dict):
+    return step_mfu(run)
