@@ -22,6 +22,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import obs
 from repro.core import circuits as C
 from repro.compile.ir import CircuitIR, CompiledClassifier, lower_netlist
 
@@ -94,7 +95,9 @@ class CircuitProgram:
         """Binarized readings `(S, F)` -> packed `(F, ceil(S/32))` uint32
         words (the megakernel's word-plane layout)."""
         from repro.kernels import circuit_sim as CS
-        return np.asarray(CS.pack_bits32(np.asarray(xbin)), dtype=np.uint32)
+        with obs.span("dispatch.pack"):
+            return np.asarray(CS.pack_bits32(np.asarray(xbin)),
+                              dtype=np.uint32)
 
     def binarize(self, x: np.ndarray) -> np.ndarray:
         """Raw readings `(S, F)` -> 0/1 uint8 via the compiled ABC
@@ -116,9 +119,13 @@ class CircuitProgram:
         """`(S, n_inputs)` 0/1 matrix -> `(S,)` int64 decoded outputs."""
         S = bits.shape[0]
         if self.backend == "np":
-            return self._netlist.eval_uint(C.pack_vectors(bits))[:S]
+            with obs.span("dispatch.pack"):
+                packed = C.pack_vectors(bits)
+            return self._netlist.eval_uint(packed)[:S]
         from repro.kernels import circuit_sim as CS
-        return self._eval_words32(CS.pack_bits32(bits))[:S]
+        with obs.span("dispatch.pack"):
+            words32 = CS.pack_bits32(bits)
+        return self._eval_words32(words32)[:S]
 
     def _eval_words32(self, words32: np.ndarray) -> np.ndarray:
         from repro.kernels import dispatch as D
@@ -143,9 +150,8 @@ class CircuitProgram:
         Applies the compiled ABC thresholds (strict `>` comparators, same
         as `ternary.abc_binarize`) before the gate plane.
         """
-        if self.thresholds is None:
-            raise ValueError("program has no ABC thresholds")
-        xbin = (np.asarray(x) > self.thresholds[None, :]).astype(np.uint8)
+        with obs.span("dispatch.binarize"):
+            xbin = self.binarize(x)
         return self.predict_bits(xbin)
 
     def scores(self, xbin: np.ndarray) -> np.ndarray:

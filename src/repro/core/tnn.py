@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import circuits as C
 from repro.core.nsga2 import NSGA2Config, NSGA2Result, nsga2
 from repro.core.pcc import PCCLibrary, PCCEntry
@@ -317,6 +318,8 @@ class TNNApproxProblem:
     hidden_bit_cache: list[np.ndarray] = field(default_factory=list)  # (n_cand, S) u8
     fixed_hbits: np.ndarray | None = None                    # (S, H) exact base
     fixed_cost: HwCost = field(default_factory=lambda: HwCost(0, 0))
+    _n_calls: int = field(default=0, init=False, repr=False,
+                          compare=False)                    # span ids
 
     def __post_init__(self):
         S = self.xbin.shape[0]
@@ -415,34 +418,45 @@ class TNNApproxProblem:
         """
         pop = np.asarray(pop, dtype=np.int64)
         P = pop.shape[0]
+        self._n_calls += 1
+        with obs.span("tnn.objective", call=self._n_calls, P=P):
+            return self._objective(pop)
+
+    def _objective(self, pop: np.ndarray) -> np.ndarray:
+        P = pop.shape[0]
         S = self.xbin.shape[0]
-        est = np.full(P, self.fixed_cost.area_mm2)
-        hbits = np.repeat(self.fixed_hbits[None], P, axis=0)     # (P, S, H)
-        for g, cache in enumerate(self.hidden_bit_cache):
-            hbits[:, :, self.hidden_idx[g]] = cache[pop[:, g]]
-            est = est + self._hidden_gene_areas[g][pop[:, g]]
         nh = len(self.hidden_idx)
         Cc = self.tnn.w2t.shape[1]
+        with obs.span("tnn.objective.gather"):
+            est = np.full(P, self.fixed_cost.area_mm2)
+            hbits = np.repeat(self.fixed_hbits[None], P, axis=0)  # (P, S, H)
+            for g, cache in enumerate(self.hidden_bit_cache):
+                hbits[:, :, self.hidden_idx[g]] = cache[pop[:, g]]
+                est = est + self._hidden_gene_areas[g][pop[:, g]]
+            subs = []
+            for o in range(Cc):
+                est = est + self._out_areas[pop[:, nh + o]]
+                subs.append(self._out_pop.take(pop[:, nh + o]))
         scores = np.empty((P, S, Cc), dtype=np.int64)
         for o in range(Cc):
-            k = pop[:, nh + o]
-            est = est + self._out_areas[k]
             col = self.tnn.w2t[:, o]
-            bits = np.concatenate([hbits[:, :, col == 1],
-                                   1 - hbits[:, :, col == -1]], axis=2)
-            if bits.shape[2] == 0:
-                scores[:, :, o] = 0
-                continue
-            packed = C.pack_vectors(bits)                        # (P, nnz, W)
-            sub = self._out_pop.take(k)
-            if self.eval_backend == "np":
-                scores[:, :, o] = sub.eval_uint(packed)[:, :S]
-            else:
-                from repro.kernels.dispatch import population_eval_pop
-                scores[:, :, o] = population_eval_pop(
-                    sub, packed, backend=self.eval_backend)[:, :S]
-        acc = (np.argmax(scores, axis=2) == self.y[None, :]).mean(axis=1)
-        return np.stack([1.0 - acc, est], axis=1)
+            with obs.span("tnn.objective.pack"):
+                bits = np.concatenate([hbits[:, :, col == 1],
+                                       1 - hbits[:, :, col == -1]], axis=2)
+                if bits.shape[2] == 0:
+                    scores[:, :, o] = 0
+                    continue
+                packed = C.pack_vectors(bits)                # (P, nnz, W)
+            with obs.span("tnn.objective.eval"):
+                if self.eval_backend == "np":
+                    scores[:, :, o] = subs[o].eval_uint(packed)[:, :S]
+                else:
+                    from repro.kernels.dispatch import population_eval_pop
+                    scores[:, :, o] = population_eval_pop(
+                        subs[o], packed, backend=self.eval_backend)[:, :S]
+        with obs.span("tnn.objective.score"):
+            acc = (np.argmax(scores, axis=2) == self.y[None, :]).mean(axis=1)
+            return np.stack([1.0 - acc, est], axis=1)
 
     def optimize(self, cfg: NSGA2Config) -> NSGA2Result:
         seed = np.zeros((1, self.n_genes), dtype=np.int64)   # all-exact individual

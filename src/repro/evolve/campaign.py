@@ -43,6 +43,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import CheckpointManager
 from repro.core.nsga2 import (NSGA2Driver, NSGA2State, _memoized,
                               encode_rng_state, extract_front)
@@ -271,12 +272,17 @@ class Campaign:
         else:
             for _ in range(self.cfg.gens_per_epoch):
                 for i, driver in enumerate(self.drivers):
-                    self.states[i] = driver.step(self.states[i])
-        for state in self.states:
-            self.archive.update(*extract_front(state.pop, state.F))
-        migrate_ring(self.states, self.cfg.migrate_k)
+                    with obs.span("evolve.generation", epoch=epoch,
+                                  island=i):
+                        self.states[i] = driver.step(self.states[i])
+        with obs.span("evolve.archive"):
+            for state in self.states:
+                self.archive.update(*extract_front(state.pop, state.F))
+        with obs.span("evolve.migrate"):
+            migrate_ring(self.states, self.cfg.migrate_k)
         self._record_cache_row(epoch, stats)
-        self._save(epoch)
+        with obs.span("evolve.checkpoint", epoch=epoch):
+            self._save(epoch)
         self.next_epoch = epoch + 1
         return epoch
 

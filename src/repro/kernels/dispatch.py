@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
 from repro.core.circuits import NetlistPopulation
 
 BACKENDS = ("np", "swar", "pallas")
@@ -92,20 +93,31 @@ def _pallas_kwargs(block_words) -> dict:
     return {} if block_words is None else {"block_words": int(block_words)}
 
 
+def _device_eval_fn(backend: str, block_words):
+    """The device executor of `backend`.  The Pallas one opens its own
+    plan, h2d and launch spans; the SWAR scan is one jitted launch."""
+    if backend == "pallas":
+        from functools import partial
+
+        from repro.kernels import pallas_circuit_sim as PS
+        return partial(PS.population_eval_uint,
+                       **_pallas_kwargs(block_words))
+    from repro.kernels import circuit_sim as CS
+
+    def launch(*args):
+        with obs.span("dispatch.launch"):
+            return CS.population_eval_uint(*args)
+    return launch
+
+
 def _eval_device(op, in0, in1, outputs, packed_u64, n_inputs, backend,
                  devices, block_words=None) -> np.ndarray:
     import jax
 
     from repro.kernels import circuit_sim as CS
-    if backend == "pallas":
-        from functools import partial
-
-        from repro.kernels import pallas_circuit_sim as PS
-        eval_fn = partial(PS.population_eval_uint,
-                          **_pallas_kwargs(block_words))
-    else:
-        eval_fn = CS.population_eval_uint
-    words32 = CS.pack_words32(packed_u64)
+    eval_fn = _device_eval_fn(backend, block_words)
+    with obs.span("dispatch.pack"):
+        words32 = CS.pack_words32(packed_u64)
     per_individual = words32.ndim == 3
     devices = list(devices) if devices is not None else jax.local_devices()
     P = op.shape[0]
@@ -116,8 +128,11 @@ def _eval_device(op, in0, in1, outputs, packed_u64, n_inputs, backend,
         shard = (op[sl], in0[sl], in1[sl], outputs[sl],
                  words32[sl] if per_individual else words32)
         if len(slices) > 1:
-            shard = tuple(jax.device_put(a, dev) for a in shard)
-        outs.append(np.asarray(eval_fn(*shard, n_inputs)))
+            with obs.span("dispatch.h2d"):
+                shard = tuple(jax.device_put(a, dev) for a in shard)
+        out = eval_fn(*shard, n_inputs)
+        with obs.span("dispatch.fetch"):
+            outs.append(np.asarray(out))
     return np.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
 
@@ -203,15 +218,7 @@ def program_eval_words(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
 
     import jax
 
-    from repro.kernels import circuit_sim as CS
-    if backend == "pallas":
-        from functools import partial
-
-        from repro.kernels import pallas_circuit_sim as PS
-        eval_fn = partial(PS.population_eval_uint,
-                          **_pallas_kwargs(block_words))
-    else:
-        eval_fn = CS.population_eval_uint
+    eval_fn = _device_eval_fn(backend, block_words)
     plan = (np.asarray(op, dtype=np.int32), np.asarray(in0, dtype=np.int32),
             np.asarray(in1, dtype=np.int32),
             np.asarray(outputs, dtype=np.int32))
@@ -220,8 +227,11 @@ def program_eval_words(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
         if len(devices) != 1:
             raise ValueError(f"a serving dispatch runs on one device; got "
                              f"{len(devices)}")
-        words32 = jax.device_put(words32, devices[0])
-    return np.asarray(eval_fn(*plan, words32, n_inputs)).astype(np.int64)
+        with obs.span("dispatch.h2d"):
+            words32 = jax.device_put(words32, devices[0])
+    out = eval_fn(*plan, words32, n_inputs)
+    with obs.span("dispatch.fetch"):
+        return np.asarray(out).astype(np.int64)
 
 
 def fleet_eval_words(plans: list, words_list: list, backend: str = "pallas",

@@ -57,10 +57,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.kernels.circuit_sim import _C0_TBL, _CA_TBL, _CAB_TBL, _CB_TBL
 
 DEFAULT_BLOCK_WORDS = 128
 LANES = 128
+# kernel names, so a profile's device rows read the same after a refactor
+GATE_WALK = "tnn_gate_walk"         # one program (or a genome population)
+FLEET_WALK = "tnn_fleet_walk"       # the multi-program megakernel
 
 
 def _interpret() -> bool:
@@ -120,13 +124,15 @@ def _kernel(plan_ref, outputs_ref, words_ref, out_ref, vals_ref, *,
 
 
 @partial(jax.jit, static_argnames=("n_inputs", "block_words", "decode",
-                                   "interpret"))
+                                   "interpret", "name"))
 def _fused_padded(plan, outputs, words32, *, n_inputs: int,
-                  block_words: int, decode: bool, interpret: bool):
+                  block_words: int, decode: bool, interpret: bool,
+                  name: str = GATE_WALK):
     """`plan` (P, 6, G) int32, `outputs` (P, 1, n_out) int32, `words32`
     (n_inputs, Wp) or (P, n_inputs, Wp) uint32 with Wp a multiple of
     `block_words`.  Returns (P, Wp*32) int32 decoded integers, or
-    (P, n_out, Wp) uint32 output words when `decode` is False."""
+    (P, n_out, Wp) uint32 output words when `decode` is False.  `name`
+    names the kernel and its scope."""
     P, _, G = plan.shape
     n_out = outputs.shape[2]
     Wp = words32.shape[-1]
@@ -136,20 +142,22 @@ def _fused_padded(plan, outputs, words32, *, n_inputs: int,
                   if words.ndim == 2 else
                   pl.BlockSpec((1, n_inputs, bw), lambda p, w: (p, 0, w)))
     rows = 32 if decode else n_out
-    out = pl.pallas_call(
-        partial(_kernel, n_inputs=n_inputs, n_gates=G, n_out=n_out,
-                decode=decode),
-        grid=(P, Wp // bw),
-        in_specs=[pl.BlockSpec((1, 6, G), lambda p, w: (p, 0, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, 1, n_out), lambda p, w: (p, 0, 0),
-                               memory_space=pltpu.SMEM),
-                  words_spec],
-        out_specs=pl.BlockSpec((1, rows, bw), lambda p, w: (p, 0, w)),
-        out_shape=jax.ShapeDtypeStruct((P, rows, Wp), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((n_inputs + G, bw), jnp.int32)],
-        interpret=interpret,
-    )(plan, outputs, words)
+    with jax.named_scope(name):
+        out = pl.pallas_call(
+            partial(_kernel, n_inputs=n_inputs, n_gates=G, n_out=n_out,
+                    decode=decode),
+            grid=(P, Wp // bw),
+            in_specs=[pl.BlockSpec((1, 6, G), lambda p, w: (p, 0, 0),
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec((1, 1, n_out), lambda p, w: (p, 0, 0),
+                                   memory_space=pltpu.SMEM),
+                      words_spec],
+            out_specs=pl.BlockSpec((1, rows, bw), lambda p, w: (p, 0, w)),
+            out_shape=jax.ShapeDtypeStruct((P, rows, Wp), jnp.int32),
+            scratch_shapes=[pltpu.VMEM((n_inputs + G, bw), jnp.int32)],
+            interpret=interpret,
+            name=name,
+        )(plan, outputs, words)
     if decode:
         return out.transpose(0, 2, 1).reshape(P, Wp * 32)
     return jax.lax.bitcast_convert_type(out, jnp.uint32)
@@ -175,19 +183,24 @@ def _plan_table(op, in0, in1) -> np.ndarray:
 
 
 def _run(op, in0, in1, outputs, words32, n_inputs: int, *,
-         block_words: int | None, decode: bool) -> jax.Array:
-    plan = _plan_table(op, in0, in1)
-    outputs = np.asarray(outputs, dtype=np.int32)[:, None, :]
-    words32 = jnp.asarray(words32, dtype=jnp.uint32)
-    W = words32.shape[-1]
-    bw = _word_tile(W, block_words)
-    wpad = (-W) % bw
-    if wpad:
-        pad_width = [(0, 0)] * (words32.ndim - 1) + [(0, wpad)]
-        words32 = jnp.pad(words32, pad_width)
-    return _fused_padded(jnp.asarray(plan), jnp.asarray(outputs), words32,
-                         n_inputs=n_inputs, block_words=bw, decode=decode,
-                         interpret=_interpret())
+         block_words: int | None, decode: bool,
+         name: str = GATE_WALK) -> jax.Array:
+    with obs.span("dispatch.plan"):
+        plan = _plan_table(op, in0, in1)
+        outputs = np.asarray(outputs, dtype=np.int32)[:, None, :]
+    with obs.span("dispatch.h2d"):
+        words32 = jnp.asarray(words32, dtype=jnp.uint32)
+        W = words32.shape[-1]
+        bw = _word_tile(W, block_words)
+        wpad = (-W) % bw
+        if wpad:
+            pad_width = [(0, 0)] * (words32.ndim - 1) + [(0, wpad)]
+            words32 = jnp.pad(words32, pad_width)
+        plan, outputs = jnp.asarray(plan), jnp.asarray(outputs)
+    with obs.span("dispatch.launch"):
+        return _fused_padded(plan, outputs, words32, n_inputs=n_inputs,
+                             block_words=bw, decode=decode,
+                             interpret=_interpret(), name=name)
 
 
 def simulate_population(op, in0, in1, outputs, words32, n_inputs: int, *,
@@ -269,35 +282,38 @@ def fleet_eval_words(plans, words_list, *,
                              f"match n_inputs={n_in}")
         norm.append((op, in0, in1, outputs, int(n_in), w))
 
-    T = len(norm)
-    n_in_max = max(p[4] for p in norm)
-    G_max = max(p[0].shape[0] for p in norm) + 1      # +1: shared zero node
-    n_out_max = max(p[3].shape[0] for p in norm)
-    W_list = [p[5].shape[1] for p in norm]
-    W_max = max(W_list)
-    if W_max == 0:
-        return [np.zeros(0, dtype=np.int32) for _ in norm]
+    with obs.span("dispatch.plan"):      # the padded manifest plan
+        T = len(norm)
+        n_in_max = max(p[4] for p in norm)
+        G_max = max(p[0].shape[0] for p in norm) + 1  # +1: zero node
+        n_out_max = max(p[3].shape[0] for p in norm)
+        W_list = [p[5].shape[1] for p in norm]
+        W_max = max(W_list)
+        if W_max == 0:
+            return [np.zeros(0, dtype=np.int32) for _ in norm]
 
-    zero_node = n_in_max + G_max - 1    # the trailing CONST0 pad gate
-    op_t = np.full((T, G_max), int(Gate.CONST0), dtype=np.int16)
-    in0_t = np.zeros((T, G_max), dtype=np.int32)
-    in1_t = np.zeros((T, G_max), dtype=np.int32)
-    out_t = np.full((T, n_out_max), zero_node, dtype=np.int32)
-    words_t = np.zeros((T, n_in_max, W_max), dtype=np.uint32)
+        zero_node = n_in_max + G_max - 1    # the trailing CONST0 pad gate
+        op_t = np.full((T, G_max), int(Gate.CONST0), dtype=np.int16)
+        in0_t = np.zeros((T, G_max), dtype=np.int32)
+        in1_t = np.zeros((T, G_max), dtype=np.int32)
+        out_t = np.full((T, n_out_max), zero_node, dtype=np.int32)
+        words_t = np.zeros((T, n_in_max, W_max), dtype=np.uint32)
 
-    def remap(idx: np.ndarray, n_in: int) -> np.ndarray:
-        # tenant node numbering: inputs 0..n_in-1, gates n_in.. — shift the
-        # gate nodes past the padded input rows
-        return np.where(idx >= n_in, idx + (n_in_max - n_in), idx)
+        def remap(idx: np.ndarray, n_in: int) -> np.ndarray:
+            # tenant node numbering: inputs 0..n_in-1, gates n_in.. —
+            # shift the gate nodes past the padded input rows
+            return np.where(idx >= n_in, idx + (n_in_max - n_in), idx)
 
-    for t, (op, in0, in1, outputs, n_in, w) in enumerate(norm):
-        G = op.shape[0]
-        op_t[t, :G] = op
-        in0_t[t, :G] = remap(in0, n_in)
-        in1_t[t, :G] = remap(in1, n_in)
-        out_t[t, : outputs.shape[0]] = remap(outputs, n_in)
-        words_t[t, :n_in, : w.shape[1]] = w
+        for t, (op, in0, in1, outputs, n_in, w) in enumerate(norm):
+            G = op.shape[0]
+            op_t[t, :G] = op
+            in0_t[t, :G] = remap(in0, n_in)
+            in1_t[t, :G] = remap(in1, n_in)
+            out_t[t, : outputs.shape[0]] = remap(outputs, n_in)
+            words_t[t, :n_in, : w.shape[1]] = w
 
-    out = np.asarray(fused_eval_uint(op_t, in0_t, in1_t, out_t, words_t,
-                                     n_in_max, block_words=block_words))
+    out = _run(op_t, in0_t, in1_t, out_t, words_t, n_in_max,
+               block_words=block_words, decode=True, name=FLEET_WALK)
+    with obs.span("dispatch.fetch"):
+        out = np.asarray(out)
     return [out[t, : W_list[t] * 32] for t in range(T)]

@@ -30,6 +30,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro import obs
 from repro.compile.program import CircuitProgram
 
 STATS_WINDOW = 4096
@@ -53,6 +54,14 @@ class _Ring:
     def push(self, v: float) -> None:
         self._buf[self._pushed % self._buf.shape[0]] = v
         self._pushed += 1
+
+    def extend(self, values: np.ndarray) -> None:
+        """`push` each of `values` in order, in one vector write."""
+        cap = self._buf.shape[0]
+        kept = values[-cap:]
+        first = self._pushed + len(values) - len(kept)
+        self._buf[(first + np.arange(len(kept))) % cap] = kept
+        self._pushed += len(values)
 
     def __len__(self) -> int:
         return min(self._pushed, self._buf.shape[0])
@@ -110,12 +119,25 @@ class ServeStats:
             if deadline_ms is not None and latency_ms > deadline_ms:
                 self.n_slo_miss += 1
 
+    def record_requests(self, latencies_ms: np.ndarray,
+                        deadline_ms=None) -> None:
+        """`record_request` for a whole batch under one lock; `deadline_ms`
+        is None, one budget, or one per request (NaN: none)."""
+        lat = np.asarray(latencies_ms, dtype=np.float64).reshape(-1)
+        miss = (0 if deadline_ms is None else int(np.count_nonzero(
+            lat > np.asarray(deadline_ms, dtype=np.float64))))
+        with self._lock:
+            self.n_requests += lat.shape[0]
+            self.request_ms.extend(lat)
+            self.n_slo_miss += miss
+
     def record_shed(self, n: int = 1) -> None:
         with self._lock:
             self.n_shed += n
 
     @property
     def readings_per_s(self) -> float:
+        """Readings per second of dispatch (`busy_s`), not throughput."""
         return self.n_readings / self.busy_s if self.busy_s > 0 else 0.0
 
     def percentile_ms(self, q: float) -> float:
@@ -155,6 +177,22 @@ class SensorRequest:
     def slo_miss(self) -> bool:
         return (self.deadline_ms is not None and self.latency_ms is not None
                 and self.latency_ms > self.deadline_ms)
+
+
+def attach_labels(group: list, labels: np.ndarray
+                  ) -> tuple[np.ndarray, np.ndarray]:
+    """Set each dispatched request's label and submit -> label latency.
+
+    Returns the latencies and the deadlines (NaN where a request has
+    none), in ms, for `ServeStats.record_requests`."""
+    t_done = time.perf_counter()
+    lat = np.empty(len(group))
+    dl = np.empty(len(group))
+    for i, (r, lbl) in enumerate(zip(group, labels)):
+        r.label = int(lbl)
+        r.latency_ms = lat[i] = (t_done - r._t_submit) * 1e3
+        dl[i] = np.nan if r.deadline_ms is None else r.deadline_ms
+    return lat, dl
 
 
 class CircuitServingEngine:
@@ -244,11 +282,7 @@ class CircuitServingEngine:
     def complete(self, group: list[SensorRequest],
                  labels: np.ndarray) -> None:
         """Attach labels + latency to dispatched requests (stats included)."""
-        t_done = time.perf_counter()
-        for r, lbl in zip(group, labels):
-            r.label = int(lbl)
-            r.latency_ms = (t_done - r._t_submit) * 1e3
-            self.stats.record_request(r.latency_ms, r.deadline_ms)
+        self.stats.record_requests(*attach_labels(group, labels))
 
     # -- bulk path ----------------------------------------------------------
     def classify_stream(self, x: np.ndarray) -> np.ndarray:
@@ -297,24 +331,28 @@ class CircuitServingEngine:
         if B > self.max_batch:
             raise ValueError(f"batch of {B} exceeds max_batch "
                              f"{self.max_batch}")
-        xbin = (self.program.binarize(x)
-                if self.program.thresholds is not None
-                else np.asarray(x, dtype=np.uint8))
-        if B < self.max_batch:
-            pad = np.zeros((self.max_batch - B, xbin.shape[1]),
-                           dtype=xbin.dtype)
-            xbin = np.concatenate([xbin, pad], axis=0)
-        return self.program.pack_input_bits(xbin), B
+        return self.program.pack_input_bits(self._binarize_padded(x)), B
+
+    def _binarize_padded(self, x: np.ndarray) -> np.ndarray:
+        """Readings -> 0/1 bits through the program's thresholds (or raw
+        bits when it has none), zero-padded to the compiled `max_batch`
+        rows; pad rows decode through the circuit and are dropped."""
+        with obs.span("dispatch.binarize"):
+            xbin = (self.program.binarize(x)
+                    if self.program.thresholds is not None
+                    else np.asarray(x, dtype=np.uint8))
+            B = xbin.shape[0]
+            if B < self.max_batch:
+                pad = np.zeros((self.max_batch - B, xbin.shape[1]),
+                               dtype=xbin.dtype)
+                xbin = np.concatenate([xbin, pad], axis=0)
+        return xbin
 
     def _dispatch(self, x: np.ndarray) -> np.ndarray:
         """One padded fixed-shape batch through the program (timed)."""
         B = x.shape[0]
-        if B < self.max_batch:      # pad to the compiled shape
-            pad = np.zeros((self.max_batch - B, x.shape[1]), dtype=x.dtype)
-            x = np.concatenate([x, pad], axis=0)
         t0 = time.perf_counter()
-        labels = (self.program.predict(x) if self.program.thresholds is not None
-                  else self.program.predict_bits(x.astype(np.uint8)))
+        labels = self.program.predict_bits(self._binarize_padded(x))
         dt = time.perf_counter() - t0
         self.stats.record(B, dt)
         return labels[:B]

@@ -76,13 +76,14 @@ from pathlib import Path
 
 import numpy as np
 
+from repro import obs
 from repro.compile.artifact import load_manifest_doc, load_program
 from repro.compile.program import CircuitProgram
 from repro.serve.autoscale import (QOS_CLASSES, Autoscaler, AutoscaleConfig,
                                    TenantSignals, TokenBucket)
 from repro.serve.batcher import MicroBatcher, QueuedItem
 from repro.serve.engine import (STATS_WINDOW, CircuitServingEngine,
-                                ServeStats)
+                                ServeStats, attach_labels)
 from repro.serve.replicas import EngineReplica, ReplicaPool, make_replica
 from repro.serve.shadow import ShadowComparator
 from repro.serve.workers import WorkerHost
@@ -923,6 +924,13 @@ class ClassifierFleet:
                   entries: list[QueuedItem]) -> bool:
         """Serve one popped batch; returns True iff it completed cleanly."""
         reqs: list[FleetRequest] = [e.item for e in entries]
+        obs.add("fleet.queue_wait", self._clock() - entries[0].t_submit)
+        with obs.span("fleet.dispatch", tenant=tenant.name,
+                      batch=reqs[0].batch_uid, replica=replica.index):
+            return self._serve_batch(tenant, replica, reqs)
+
+    def _serve_batch(self, tenant: _Tenant, replica: EngineReplica,
+                     reqs: list[FleetRequest]) -> bool:
         # a shadow's dispatches never touch fleet-level stats or the fleet
         # error log: mirrored traffic is an experiment riding alongside the
         # SLO-accounted serving path, and a broken candidate must show up
@@ -931,7 +939,8 @@ class ClassifierFleet:
         host = (self._worker_hosts.get(tenant.spec.backend)
                 if tenant.worker_key is not None else None)
         try:
-            x = self._gather_batch(reqs)
+            with obs.span("dispatch.gather"):
+                x = self._gather_batch(reqs)
             # the dispatch timing deliberately includes the worker-path IPC
             # (slab copy + queue round-trip): it is the cost the deadline
             # policy must budget for, not just device time
@@ -949,26 +958,31 @@ class ClassifierFleet:
                 r.error = msg
                 r._complete()
             return False
-        tenant.est_dispatch_s = 0.7 * tenant.est_dispatch_s + 0.3 * dt
-        tenant.last_dispatch_s = dt
-        if not is_shadow:
-            self.stats.record(len(reqs), dt)
-        tenant.stats.record(len(reqs), dt)
-        if host is not None:
-            # keep the replica-level ledger honest in worker mode too:
-            # timing/labels came from the worker proc, but the attach path
-            # (label, latency, request stats) is identical
-            replica.engine.stats.record(len(reqs), dt)
-        # FleetRequest carries the same completion fields as SensorRequest,
-        # so the engine's label/latency attach is reused verbatim (request
-        # stats land on the replica's engine; tenant + fleet get them here)
-        replica.engine.complete(reqs, labels)
-        for r in reqs:
+        with obs.span("fleet.complete"):
+            tenant.est_dispatch_s = 0.7 * tenant.est_dispatch_s + 0.3 * dt
+            tenant.last_dispatch_s = dt
             if not is_shadow:
-                self.stats.record_request(r.latency_ms, r.deadline_ms)
-            tenant.stats.record_request(r.latency_ms, r.deadline_ms)
-            r._complete()
+                self.stats.record(len(reqs), dt)
+            tenant.stats.record(len(reqs), dt)
+            if host is not None:
+                # keep the replica-level batch ledger honest in worker
+                # mode too: timing/labels came from the worker proc
+                replica.engine.stats.record(len(reqs), dt)
+            self._complete_requests(tenant, reqs, labels)
         return True
+
+    def _complete_requests(self, tenant: _Tenant, reqs: list[FleetRequest],
+                           labels: np.ndarray) -> None:
+        """Attach labels and latencies, record the request samples once a
+        batch on the tenant (and the fleet, unless a shadow's), then fire
+        each request's callbacks.  The replica engine keeps no request
+        samples: nothing reads them."""
+        lat, deadlines = attach_labels(reqs, labels)
+        if tenant.shadow_of is None:
+            self.stats.record_requests(lat, deadlines)
+        tenant.stats.record_requests(lat, deadlines)
+        for r in reqs:
+            r._complete()
 
     def _dispatch_fused(self, jobs: list) -> bool:
         """Serve MANY tenants' popped batches in one megakernel launch.
@@ -986,6 +1000,13 @@ class ClassifierFleet:
         failure fails every request of every job — the whole launch is
         the unit of execution.
         """
+        now = self._clock()
+        for _, _, entries in jobs:
+            obs.add("fleet.queue_wait", now - entries[0].t_submit)
+        with obs.span("fleet.dispatch", tenants=len(jobs)):
+            return self._serve_fused(jobs)
+
+    def _serve_fused(self, jobs: list) -> bool:
         from repro.kernels import dispatch as D
 
         prepared = []
@@ -993,8 +1014,9 @@ class ClassifierFleet:
             plans, words_list = [], []
             for tenant, replica, entries in jobs:
                 reqs = [e.item for e in entries]
-                words32, B = replica.engine.prepare_packed_batch(
-                    self._gather_batch(reqs))
+                with obs.span("dispatch.gather"):
+                    x = self._gather_batch(reqs)
+                words32, B = replica.engine.prepare_packed_batch(x)
                 plans.append(replica.engine.program.plan())
                 words_list.append(words32)
                 prepared.append((tenant, replica, reqs, B))
@@ -1012,26 +1034,22 @@ class ClassifierFleet:
                     e.item.error = msg
                     e.item._complete()
             return False
-        live_readings = sum(len(reqs) for t, _, reqs, _ in prepared
-                            if t.shadow_of is None)
-        if live_readings:
-            self.stats.record(live_readings, dt)   # one launch = one batch
-        self._megakernel_launches += 1
-        self._megakernel_peak_tenants = max(self._megakernel_peak_tenants,
-                                            len(jobs))
-        for (tenant, replica, reqs, B), out in zip(prepared, outs):
-            labels = np.asarray(out[:B], dtype=np.int32)
-            is_shadow = tenant.shadow_of is not None
-            tenant.est_dispatch_s = 0.7 * tenant.est_dispatch_s + 0.3 * dt
-            tenant.last_dispatch_s = dt
-            tenant.stats.record(len(reqs), dt)
-            replica.engine.stats.record(len(reqs), dt)
-            replica.engine.complete(reqs, labels)
-            for r in reqs:
-                if not is_shadow:
-                    self.stats.record_request(r.latency_ms, r.deadline_ms)
-                tenant.stats.record_request(r.latency_ms, r.deadline_ms)
-                r._complete()
+        with obs.span("fleet.complete"):
+            live_readings = sum(len(reqs) for t, _, reqs, _ in prepared
+                                if t.shadow_of is None)
+            if live_readings:
+                self.stats.record(live_readings, dt)   # one launch, one batch
+            self._megakernel_launches += 1
+            self._megakernel_peak_tenants = max(
+                self._megakernel_peak_tenants, len(jobs))
+            for (tenant, replica, reqs, B), out in zip(prepared, outs):
+                labels = np.asarray(out[:B], dtype=np.int32)
+                tenant.est_dispatch_s = (0.7 * tenant.est_dispatch_s
+                                         + 0.3 * dt)
+                tenant.last_dispatch_s = dt
+                tenant.stats.record(len(reqs), dt)
+                replica.engine.stats.record(len(reqs), dt)
+                self._complete_requests(tenant, reqs, labels)
         return True
 
     # -- shadow deployment ---------------------------------------------------
@@ -1409,7 +1427,8 @@ class ClassifierFleet:
         fleet last synced to — so an operator (or the autopilot) can tell
         exactly which emitted design is live without touching the emit
         dir.  Tenants with a live shadow get a `"shadow"` sub-dict with
-        the comparator's running verdict evidence.
+        the comparator's running verdict evidence.  `"spans"` is this
+        process's span and counter table (`repro.obs.snapshot()`).
 
         The snapshot is *consistent*: every backend's scheduler condition
         is held (in one canonical order, so this cannot deadlock against
@@ -1456,6 +1475,7 @@ class ClassifierFleet:
                 "fleet": self.stats.summary(),
                 "manifest_generation": self._manifest_generation,
                 "tenants": tenants,
+                "spans": obs.snapshot(),
             }
             if self.megakernel:
                 out["megakernel"] = {

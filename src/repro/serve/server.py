@@ -57,6 +57,7 @@ import socket
 import threading
 from pathlib import Path
 
+from repro import obs
 from repro.compile.artifact import manifest_path
 from repro.serve import protocol as P
 from repro.serve.fleet import ClassifierFleet, FleetOverloadError
@@ -235,20 +236,23 @@ class FleetServer:
             if _CLOSE in items:     # close sentinel — may arrive mid-burst
                 closing = True      # (a dispatch completing after the
                 items = [it for it in items if it is not _CLOSE]  # disconnect)
-            chunks, results = [], []
-            for it in items:
-                if it[0] == "raw":
-                    chunks.append(it[1])
-                else:
-                    results.append(it[1:])
-            if results:
-                if conn.version >= 2 and len(results) > 1:
-                    rids, labels, lats = zip(*results)
-                    chunks.append(P.encode_result_batch(rids, labels, lats))
-                else:
-                    chunks.extend(P.encode_result(*r) for r in results)
+            with obs.span("serve.write", items=len(items)):
+                chunks, results = [], []
+                for it in items:
+                    if it[0] == "raw":
+                        chunks.append(it[1])
+                    else:
+                        results.append(it[1:])
+                if results:
+                    if conn.version >= 2 and len(results) > 1:
+                        rids, labels, lats = zip(*results)
+                        chunks.append(
+                            P.encode_result_batch(rids, labels, lats))
+                    else:
+                        chunks.extend(P.encode_result(*r) for r in results)
+                if chunks:
+                    writer.write(b"".join(chunks))
             if chunks:
-                writer.write(b"".join(chunks))
                 try:
                     await writer.drain()
                 except (ConnectionError, OSError):
@@ -273,33 +277,39 @@ class FleetServer:
 
     def _handle_submit_batch(self, msg: P.Message, conn: _ConnState) -> None:
         """One SUBMIT_BATCH frame -> the fleet's single-lock fast path."""
-        try:
-            reqs, shed_idx, retry_ms = self.fleet.submit_many(
-                msg.tenant, msg.readings, msg.deadlines_ms)
-        except (KeyError, ValueError, RuntimeError) as exc:
-            err = str(exc)
-            for rid in msg.req_ids:     # fail every row loudly, none hang
-                conn.send_raw(P.encode_error(int(rid), err))
-            return
-        for req, rid in zip(reqs, msg.req_ids):
-            req.add_done_callback(self._completion_callback(int(rid), conn))
-        for i in shed_idx:
-            conn.send_raw(P.encode_shed(int(msg.req_ids[i]), retry_ms))
+        with obs.span("serve.frame.admit", tenant=msg.tenant) as sp:
+            try:
+                reqs, shed_idx, retry_ms = self.fleet.submit_many(
+                    msg.tenant, msg.readings, msg.deadlines_ms)
+            except (KeyError, ValueError, RuntimeError) as exc:
+                err = str(exc)
+                for rid in msg.req_ids:     # fail every row loudly
+                    conn.send_raw(P.encode_error(int(rid), err))
+                return
+            if reqs:
+                sp.set(batch=reqs[0].batch_uid)
+            for req, rid in zip(reqs, msg.req_ids):
+                req.add_done_callback(
+                    self._completion_callback(int(rid), conn))
+            for i in shed_idx:
+                conn.send_raw(P.encode_shed(int(msg.req_ids[i]), retry_ms))
 
     async def _handle_message(self, msg: P.Message,
                               conn: _ConnState) -> None:
         if msg.type == P.MSG_SUBMIT:
-            try:
-                req = self.fleet.submit(msg.tenant, msg.readings,
-                                        deadline_ms=msg.deadline_ms)
-            except FleetOverloadError as exc:
-                conn.send_raw(P.encode_shed(msg.req_id, exc.retry_after_ms))
-                return
-            except (KeyError, ValueError, RuntimeError) as exc:
-                conn.send_raw(P.encode_error(msg.req_id, str(exc)))
-                return
-            req.add_done_callback(self._completion_callback(msg.req_id,
-                                                            conn))
+            with obs.span("serve.frame.admit", tenant=msg.tenant):
+                try:
+                    req = self.fleet.submit(msg.tenant, msg.readings,
+                                            deadline_ms=msg.deadline_ms)
+                except FleetOverloadError as exc:
+                    conn.send_raw(P.encode_shed(msg.req_id,
+                                                exc.retry_after_ms))
+                    return
+                except (KeyError, ValueError, RuntimeError) as exc:
+                    conn.send_raw(P.encode_error(msg.req_id, str(exc)))
+                    return
+                req.add_done_callback(self._completion_callback(msg.req_id,
+                                                                conn))
         elif msg.type == P.MSG_SUBMIT_BATCH:
             self._handle_submit_batch(msg, conn)
         elif msg.type == P.MSG_LIST:
@@ -328,7 +338,8 @@ class FleetServer:
                 if not chunk:
                     break
                 for payload in framer.feed(chunk):
-                    msg = P.decode_message(payload)
+                    with obs.span("serve.frame.decode"):
+                        msg = P.decode_message(payload)
                     if not greeted:
                         if msg.type != P.MSG_HELLO:
                             raise P.ProtocolError(
