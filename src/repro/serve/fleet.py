@@ -114,6 +114,31 @@ class FleetOverloadError(RuntimeError):
         self.reason = reason
 
 
+class _FrameSink:
+    """One `submit_many` frame's completion callback, shared by its rows.
+
+    Every admitted request of the frame holds the same sink, and the
+    fleet calls it once for each run of the frame's rows that a batch
+    completes, as ``fn(rows, labels, latencies_ms, error)``: `rows` are
+    the rows' indices in the submitted frame, `labels` and
+    `latencies_ms` arrays of the same length (None on an error), and
+    `error` None or one message for all of them.
+    """
+
+    __slots__ = ("fn", "batch_uid")
+
+    def __init__(self, fn, batch_uid: int):
+        self.fn = fn
+        self.batch_uid = batch_uid
+
+    def __call__(self, reqs: list, labels, latencies_ms, error) -> None:
+        t0 = time.perf_counter()
+        rows = np.fromiter((r._row for r in reqs), np.int64, len(reqs))
+        self.fn(rows, labels, latencies_ms, error)
+        obs.add("fleet.complete.sink", time.perf_counter() - t0,
+                n=len(reqs))
+
+
 @dataclass
 class FleetRequest:
     """One routed sensor reading; completion is signalled via `result()`."""
@@ -128,6 +153,7 @@ class FleetRequest:
     batch_uid: int | None = None    # frame identity (submit_many arrivals)
     _plane: np.ndarray | None = field(default=None, repr=False)
     _row: int = 0                   # this request's row in `_plane`
+    _sink: _FrameSink | None = field(default=None, repr=False)
     _t_submit: float = 0.0
     _event: threading.Event = field(default_factory=threading.Event,
                                     repr=False)
@@ -739,7 +765,7 @@ class ClassifierFleet:
             return req
 
     def submit_many(self, tenant: str, readings: np.ndarray,
-                    deadlines_ms=None
+                    deadlines_ms=None, on_done=None
                     ) -> tuple[list[FleetRequest], np.ndarray, float]:
         """Queue a whole `(B, F)` frame under one scheduler-lock acquisition.
 
@@ -760,6 +786,12 @@ class ClassifierFleet:
         backoff hint for them (0.0 when nothing shed).  `deadlines_ms` is
         None, a scalar, or one value per row; NaN rows use the tenant's
         default budget.
+
+        `on_done`, when given, completes the frame in bulk: every admitted
+        request holds one shared `_FrameSink`, called once for each run of
+        the frame's rows a batch completes, as ``on_done(rows, labels,
+        latencies_ms, error)`` (see `_FrameSink`).  The requests'
+        `result()`, `done()` and `add_done_callback` work as without it.
 
         A malformed deadline table (any non-positive finite row) rejects
         the *whole* frame with ValueError before any row is admitted,
@@ -823,6 +855,8 @@ class ClassifierFleet:
                     batch_uid = self._next_batch_uid
                     self._next_batch_uid += 1
                 default = t.spec.deadline_ms
+                sink = (None if on_done is None
+                        else _FrameSink(on_done, batch_uid))
                 reqs = []
                 for i in range(n_admit):
                     d = default if dls is None else float(dls[i])
@@ -831,7 +865,7 @@ class ClassifierFleet:
                     reqs.append(FleetRequest(
                         uid=uid0 + i, tenant=tenant, readings=x[i],
                         deadline_ms=d, batch_uid=batch_uid,
-                        _plane=x, _row=i))
+                        _plane=x, _row=i, _sink=sink))
                 entries = t.batcher.submit_many(
                     reqs, now=self._clock(),
                     deadlines_ms=[r.deadline_ms for r in reqs])
@@ -954,9 +988,7 @@ class ClassifierFleet:
             msg = f"{type(exc).__name__}: {exc}"
             if not is_shadow:
                 self.errors.append(f"{tenant.name}: {msg}")
-            for r in reqs:
-                r.error = msg
-                r._complete()
+            self._finish(reqs, error=msg)
             return False
         with obs.span("fleet.complete"):
             tenant.est_dispatch_s = 0.7 * tenant.est_dispatch_s + 0.3 * dt
@@ -974,15 +1006,48 @@ class ClassifierFleet:
     def _complete_requests(self, tenant: _Tenant, reqs: list[FleetRequest],
                            labels: np.ndarray) -> None:
         """Attach labels and latencies, record the request samples once a
-        batch on the tenant (and the fleet, unless a shadow's), then fire
-        each request's callbacks.  The replica engine keeps no request
+        batch on the tenant (and the fleet, unless a shadow's), then
+        complete the requests.  The replica engine keeps no request
         samples: nothing reads them."""
         lat, deadlines = attach_labels(reqs, labels)
         if tenant.shadow_of is None:
             self.stats.record_requests(lat, deadlines)
         tenant.stats.record_requests(lat, deadlines)
+        self._finish(reqs, labels, lat)
+
+    def _finish(self, reqs: list[FleetRequest], labels=None,
+                latencies_ms=None, error: str | None = None) -> None:
+        """The one way requests complete, served or failed.
+
+        Each request's event is set and its own callbacks run (shadow
+        pairing, unary users); then each frame sink is called once for
+        every consecutive run of its rows in `reqs` (a batch is usually
+        one frame, but a frame can span dispatches and several frames
+        can share one batch).  `labels` and `latencies_ms` are the
+        batch's, in `reqs` order; `error` fails every request instead.
+        """
+        if error is not None:
+            for r in reqs:
+                r.error = error
         for r in reqs:
             r._complete()
+        i, n = 0, len(reqs)
+        while i < n:
+            sink = reqs[i]._sink
+            j = i + 1
+            while j < n and reqs[j]._sink is sink:
+                j += 1
+            if sink is not None:
+                try:
+                    sink(reqs[i:j],
+                         None if labels is None else labels[i:j],
+                         None if latencies_ms is None
+                         else latencies_ms[i:j], error)
+                except Exception as exc:    # noqa: BLE001 — a failing
+                    # sink must not leave the batch's other frames unanswered
+                    self.errors.append(f"frame {sink.batch_uid} sink: "
+                                       f"{type(exc).__name__}: {exc}")
+            i = j
 
     def _dispatch_fused(self, jobs: list) -> bool:
         """Serve MANY tenants' popped batches in one megakernel launch.
@@ -1030,9 +1095,7 @@ class ClassifierFleet:
             for tenant, replica, entries in jobs:
                 if tenant.shadow_of is None:
                     self.errors.append(f"{tenant.name}: {msg}")
-                for e in entries:
-                    e.item.error = msg
-                    e.item._complete()
+                self._finish([e.item for e in entries], error=msg)
             return False
         with obs.span("fleet.complete"):
             live_readings = sum(len(reqs) for t, _, reqs, _ in prepared
@@ -1195,10 +1258,9 @@ class ClassifierFleet:
                     old_worker.cond.notify_all()
                     new_worker.cond.notify_all()
             if not compatible:
-                for e in moved:
-                    e.item.error = (f"tenant {spec.name!r} replaced with an "
-                                    f"incompatible feature count")
-                    e.item._complete()
+                self._finish([e.item for e in moved],
+                             error=f"tenant {spec.name!r} replaced with an "
+                                   f"incompatible feature count")
 
     def retire_tenant(self, name: str, timeout: float = 30.0) -> None:
         """Remove a tenant: refuse new submits, serve the backlog, drop it."""
@@ -1401,10 +1463,9 @@ class ClassifierFleet:
             with w.cond:
                 if not drain:       # cancel the backlog deterministically
                     for t in w.tenants:
-                        for batch in t.batcher.drain():
-                            for e in batch:
-                                e.item.error = "cancelled at shutdown"
-                                e.item._complete()
+                        self._finish([e.item for b in t.batcher.drain()
+                                      for e in b],
+                                     error="cancelled at shutdown")
                 w.stop = True
                 w.cond.notify_all()
         if self._started:

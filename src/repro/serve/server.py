@@ -12,10 +12,11 @@ front ends:
   `SUBMIT_BATCH` frames that enter the fleet through the
   `ClassifierFleet.submit_many` single-lock fast path.
 * **Per-connection write coalescing** — completions are queued per
-  connection as plain tuples; the writer task drains whatever is ready
-  and, on a v2 connection, folds every ready completion into one
-  `RESULT_BATCH` frame + one ``writer.write`` call, so a thousand labels
-  cost one syscall instead of a thousand.
+  connection, a frame's as one item of vectors and a unary request's as
+  a tuple; the writer task drains whatever is ready and, on a v2
+  connection, folds every ready completion into one `RESULT_BATCH`
+  frame + one ``writer.write`` call, so a thousand labels cost one
+  syscall instead of a thousand.
 * **Connectionless UDP ingest** (`udp_port=`) — fire-and-forget mode for
   sensor swarms that cannot hold a TCP connection: each datagram is one
   SUBMIT or SUBMIT_BATCH payload (no length prefix — the datagram
@@ -29,14 +30,20 @@ WELCOME with ``min(client_version, PROTOCOL_VERSION)`` and holds the
 connection to that — a v1 client keeps its per-reading SUBMIT/RESULT
 conversation, byte-compatible with the PR 5 wire format.
 
-The fleet's dispatch threads hand finished requests to the owning
-connection's event loop via `FleetRequest.add_done_callback` +
-`loop.call_soon_threadsafe`, so no thread ever parks on a request and a
-connection can pipeline thousands of readings.  Admission-control sheds
-(`FleetOverloadError` / partial `submit_many` admission) become SHED
-frames with the `retry_after_ms` hint; bad tenants / feature counts
-become per-request ERROR frames; a protocol violation gets one
-connection-level ERROR (`CONN_ERR`) and the connection is closed.
+The fleet's dispatch threads hand finished work to the owning
+connection's event loop with `loop.call_soon_threadsafe`, so no thread
+ever parks on a request and a connection can pipeline thousands of
+readings.  A SUBMIT_BATCH frame passes one frame sink
+(`submit_many(on_done=)`): each batch that completes rows of the frame
+calls it once, with the rows' labels and latencies as arrays, and it
+makes one loop wake-up and one queue item for all of them.  A unary
+SUBMIT keeps one `FleetRequest.add_done_callback` a request.
+
+Admission-control sheds (`FleetOverloadError` / partial `submit_many`
+admission) become SHED frames with the `retry_after_ms` hint; bad
+tenants / feature counts become per-request ERROR frames; a protocol
+violation gets one connection-level ERROR (`CONN_ERR`) and the
+connection is closed.
 LIST/STATS/RELOAD are JSON-bodied admin round-trips (RELOAD runs
 `fleet.sync_manifest()`).
 
@@ -56,6 +63,8 @@ import asyncio
 import socket
 import threading
 from pathlib import Path
+
+import numpy as np
 
 from repro import obs
 from repro.compile.artifact import manifest_path
@@ -79,6 +88,14 @@ class _ConnState:
     def send_result(self, req_id: int, label: int,
                     latency_ms: float) -> None:
         self.out_q.put_nowait(("res", req_id, label, latency_ms))
+
+    def send_results(self, req_ids: np.ndarray, labels: np.ndarray,
+                     latencies_ms: np.ndarray) -> None:
+        self.out_q.put_nowait(("vec", req_ids, labels, latencies_ms))
+
+    def send_errors(self, req_ids: np.ndarray, message: str) -> None:
+        self.send_raw(b"".join(P.encode_error(int(rid), message)
+                               for rid in req_ids))
 
 
 class _UdpIngest(asyncio.DatagramProtocol):
@@ -237,19 +254,11 @@ class FleetServer:
                 closing = True      # (a dispatch completing after the
                 items = [it for it in items if it is not _CLOSE]  # disconnect)
             with obs.span("serve.write", items=len(items)):
-                chunks, results = [], []
-                for it in items:
-                    if it[0] == "raw":
-                        chunks.append(it[1])
-                    else:
-                        results.append(it[1:])
+                chunks = [it[1] for it in items if it[0] == "raw"]
+                results = [it[1:] for it in items if it[0] != "raw"]
                 if results:
-                    if conn.version >= 2 and len(results) > 1:
-                        rids, labels, lats = zip(*results)
-                        chunks.append(
-                            P.encode_result_batch(rids, labels, lats))
-                    else:
-                        chunks.extend(P.encode_result(*r) for r in results)
+                    chunks.append(self._encode_results(results,
+                                                       conn.version))
                 if chunks:
                     writer.write(b"".join(chunks))
             if chunks:
@@ -257,6 +266,38 @@ class FleetServer:
                     await writer.drain()
                 except (ConnectionError, OSError):
                     return
+
+    @staticmethod
+    def _encode_results(results: list, version: int) -> bytes:
+        """One burst's completions, in queue order: a frame's vectors and
+        unary tuples, as one RESULT_BATCH on v2 (a lone result stays a
+        RESULT), as one RESULT a reading on v1."""
+        rids, labels, lats = (
+            np.concatenate([np.asarray(r[k], dtype).reshape(-1)
+                            for r in results])
+            for k, dtype in enumerate(("<u8", "<i4", "<f8")))
+        if version >= 2 and len(rids) > 1:
+            return P.encode_result_batch(rids, labels, lats)
+        return b"".join(P.encode_result(int(rid), int(lbl), float(lat))
+                        for rid, lbl, lat in zip(rids, labels, lats))
+
+    def _frame_sink(self, req_ids: np.ndarray, conn: _ConnState):
+        """Bridge a fleet dispatch thread back onto this connection's loop
+        for one SUBMIT_BATCH frame: one wake-up a completed run of rows."""
+
+        def on_done(rows, labels, latencies_ms, error) -> None:
+            rids = req_ids[rows]
+            try:
+                if error is not None:
+                    conn.loop.call_soon_threadsafe(conn.send_errors, rids,
+                                                   error)
+                else:
+                    conn.loop.call_soon_threadsafe(
+                        conn.send_results, rids, labels, latencies_ms)
+            except RuntimeError:
+                pass        # loop already closed; connection is gone anyway
+
+        return on_done
 
     def _completion_callback(self, req_id: int, conn: _ConnState):
         """Bridge a fleet dispatch thread back onto this connection's loop."""
@@ -280,17 +321,13 @@ class FleetServer:
         with obs.span("serve.frame.admit", tenant=msg.tenant) as sp:
             try:
                 reqs, shed_idx, retry_ms = self.fleet.submit_many(
-                    msg.tenant, msg.readings, msg.deadlines_ms)
+                    msg.tenant, msg.readings, msg.deadlines_ms,
+                    on_done=self._frame_sink(msg.req_ids, conn))
             except (KeyError, ValueError, RuntimeError) as exc:
-                err = str(exc)
-                for rid in msg.req_ids:     # fail every row loudly
-                    conn.send_raw(P.encode_error(int(rid), err))
+                conn.send_errors(msg.req_ids, str(exc))   # fail every row
                 return
             if reqs:
                 sp.set(batch=reqs[0].batch_uid)
-            for req, rid in zip(reqs, msg.req_ids):
-                req.add_done_callback(
-                    self._completion_callback(int(rid), conn))
             for i in shed_idx:
                 conn.send_raw(P.encode_shed(int(msg.req_ids[i]), retry_ms))
 

@@ -25,6 +25,7 @@ import time
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.compile import CircuitProgram, lower_classifier, write_artifacts
 from repro.compile.artifact import load_manifest
 from repro.core import tnn as T
@@ -350,3 +351,187 @@ if _HAVE_HYPOTHESIS:
             popped.extend(e.item for e in batch)
         assert len(mb) == 0
         assert popped == list(range(seq))            # exactly once, in order
+
+
+# ---------------------------------------------------------------------------
+# Frame sinks: submit_many(on_done=) completes a frame's rows in bulk
+# ---------------------------------------------------------------------------
+class _SinkLog:
+    """A frame sink that records every call (from dispatch threads)."""
+
+    def __init__(self):
+        self.calls = []
+        self._lock = threading.Lock()
+
+    def __call__(self, rows, labels, latencies_ms, error):
+        with self._lock:
+            self.calls.append((np.array(rows), labels, latencies_ms, error))
+
+    def rows(self) -> np.ndarray:
+        return np.concatenate([c[0] for c in self.calls])
+
+
+def _sink_readings() -> int:
+    return obs.snapshot().get("fleet.complete.sink", {"n": 0})["n"]
+
+
+def _np_fleet(cc, max_batch: int, **kw) -> ClassifierFleet:
+    spec = TenantSpec(name="toy_a", backend="np", max_batch=max_batch,
+                      deadline_ms=60_000.0,
+                      program=CircuitProgram.from_classifier(cc,
+                                                             backend="np"))
+    return ClassifierFleet([spec], warmup=False, autostart=False, **kw)
+
+
+@pytest.mark.parametrize("backend,megakernel",
+                         [("np", False), ("pallas", True)])
+def test_frame_sink_fires_once_for_a_frame_on_one_dispatch(
+        emit_dir, backend, megakernel):
+    """A 256-row frame served by one dispatch (per-tenant or megakernel)
+    calls its sink once with every row, its labels and its latencies;
+    `fleet.complete.sink` counts the 256 readings, and each request's
+    own handle still resolves."""
+    out, ccs = emit_dir
+    fleet = ClassifierFleet.from_emit_dir(
+        out, backends=backend, tenants=["toy_a"], max_batch=256,
+        deadline_ms=60_000.0, megakernel=megakernel, autostart=False,
+        warmup=False)
+    x = np.random.default_rng(31).random((256, 9))
+    want = CircuitProgram.from_classifier(ccs["toy_a"]).predict(x)
+    log = _SinkLog()
+    before = _sink_readings()
+    reqs, shed_idx, _ = fleet.submit_many("toy_a", x, on_done=log)
+    assert len(reqs) == 256 and len(shed_idx) == 0
+    fleet.start()
+    try:
+        fleet.flush(timeout=120.0)
+        assert len(log.calls) == 1
+        rows, labels, lats, error = log.calls[0]
+        assert error is None
+        np.testing.assert_array_equal(rows, np.arange(256))
+        np.testing.assert_array_equal(labels, want)
+        assert _sink_readings() - before == 256
+        assert [r.result(1.0) for r in reqs] == [int(v) for v in want]
+        np.testing.assert_array_equal(lats, [r.latency_ms for r in reqs])
+        late = []
+        reqs[0].add_done_callback(late.append)  # done already: runs now
+        assert late == [reqs[0]]
+    finally:
+        fleet.shutdown(drain=True)
+
+
+def test_frame_sink_split_across_dispatches_answers_rows_in_order(
+        emit_dir):
+    """With `max_batch=100` a 256-row frame takes three dispatches: the
+    sink fires three times, every row is answered exactly once and in
+    row order, with labels bit-identical to `CircuitProgram.predict`."""
+    _, ccs = emit_dir
+    fleet = _np_fleet(ccs["toy_a"], max_batch=100)
+    x = np.random.default_rng(37).random((256, 9))
+    want = CircuitProgram.from_classifier(ccs["toy_a"]).predict(x)
+    log = _SinkLog()
+    before = _sink_readings()
+    fleet.submit_many("toy_a", x, on_done=log)
+    fleet.start()
+    try:
+        fleet.flush(timeout=60.0)
+        assert [len(c[0]) for c in log.calls] == [100, 100, 56]
+        assert all(c[3] is None for c in log.calls)
+        np.testing.assert_array_equal(log.rows(), np.arange(256))
+        np.testing.assert_array_equal(
+            np.concatenate([c[1] for c in log.calls]), want)
+        assert _sink_readings() - before == 256
+    finally:
+        fleet.shutdown(drain=True)
+
+
+def test_frame_sinks_sharing_one_batch_each_fire_once(emit_dir):
+    """Two frames popped into one batch: each sink fires once, with its
+    own rows only."""
+    _, ccs = emit_dir
+    fleet = _np_fleet(ccs["toy_a"], max_batch=256)
+    rng = np.random.default_rng(41)
+    ref = CircuitProgram.from_classifier(ccs["toy_a"]).predict
+    frames = [rng.random((n, 9)) for n in (64, 40)]
+    logs = [_SinkLog() for _ in frames]
+    for x, log in zip(frames, logs):
+        fleet.submit_many("toy_a", x, on_done=log)
+    fleet.start()
+    try:
+        fleet.flush(timeout=60.0)
+        assert fleet.stats_summary()["tenants"]["toy_a"]["n_batches"] == 1
+        for x, log in zip(frames, logs):
+            assert len(log.calls) == 1
+            rows, labels, _, error = log.calls[0]
+            assert error is None
+            np.testing.assert_array_equal(rows, np.arange(len(x)))
+            np.testing.assert_array_equal(labels, ref(x))
+    finally:
+        fleet.shutdown(drain=True)
+
+
+def test_frame_sink_dispatch_failure_answers_every_row(emit_dir):
+    """A failing dispatch reaches the sink as one error for the rows of
+    each batch: every row is answered, none hangs."""
+    _, ccs = emit_dir
+    fleet = _np_fleet(ccs["toy_a"], max_batch=100)
+
+    def boom(x):
+        raise RuntimeError("device lost")
+
+    for rep in fleet._tenant("toy_a").pool.replicas:
+        rep.engine.classify_batch = boom
+    log = _SinkLog()
+    reqs, _, _ = fleet.submit_many(
+        "toy_a", np.random.default_rng(43).random((256, 9)), on_done=log)
+    fleet.start()
+    try:
+        fleet.flush(timeout=60.0)
+        np.testing.assert_array_equal(log.rows(), np.arange(256))
+        for rows, labels, lats, error in log.calls:
+            assert labels is None and lats is None
+            assert "device lost" in error
+        for r in reqs:
+            with pytest.raises(RuntimeError, match="device lost"):
+                r.result(1.0)
+        assert fleet.errors
+    finally:
+        fleet.shutdown(drain=True)
+
+
+def test_shutdown_cancel_reaches_a_queued_frame_through_its_sink(emit_dir):
+    _, ccs = emit_dir
+    fleet = _np_fleet(ccs["toy_a"], max_batch=100)
+    log = _SinkLog()
+    reqs, _, _ = fleet.submit_many(
+        "toy_a", np.random.default_rng(47).random((256, 9)), on_done=log)
+    fleet.shutdown(drain=False)
+    assert len(log.calls) == 1
+    rows, labels, _, error = log.calls[0]
+    np.testing.assert_array_equal(rows, np.arange(256))
+    assert labels is None and error == "cancelled at shutdown"
+    assert all(r.done() and r.error == error for r in reqs)
+
+
+def test_shadow_observes_every_primary_of_a_sink_completed_frame(emit_dir):
+    """Shadow pairing rides per-request callbacks on the primaries; a
+    frame completed through its sink still closes every pair."""
+    _, ccs = emit_dir
+    fleet = _np_fleet(ccs["toy_a"], max_batch=100)
+    shadow = TenantSpec(
+        name="toy_a_shadow", backend="np", max_batch=100,
+        deadline_ms=60_000.0,
+        program=CircuitProgram.from_classifier(ccs["toy_a"], backend="np"))
+    fleet.deploy_shadow(shadow, "toy_a")
+    log = _SinkLog()
+    fleet.submit_many("toy_a", np.random.default_rng(53).random((256, 9)),
+                      on_done=log)
+    fleet.start()
+    try:
+        fleet.flush(timeout=60.0)
+        summary = fleet.retire_shadow("toy_a")
+        assert summary["n_mirrored"] == 256
+        assert summary["n_pairs"] == summary["n_agree"] == 256
+        np.testing.assert_array_equal(log.rows(), np.arange(256))
+    finally:
+        fleet.shutdown(drain=True)

@@ -23,6 +23,7 @@ import time
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.compile import CircuitProgram, load_program, lower_classifier
 from repro.compile.verilog import write_artifacts
 from repro.core import tnn as T
@@ -652,3 +653,130 @@ def test_sharded_server_udp_ingest_and_coalescer():
     finally:
         server.stop()
         fleet.shutdown(drain=True)
+
+
+# ---------------------------------------------------------------------------
+# Frame completion: one sink call, one loop wake-up, one RESULT_BATCH a frame
+# ---------------------------------------------------------------------------
+def _read_frame(s) -> bytes:
+    import struct as _struct
+
+    def read_n(n):
+        buf = b""
+        while len(buf) < n:
+            chunk = s.recv(n - len(buf))
+            assert chunk, "server hung up"
+            buf += chunk
+        return buf
+
+    (ln,) = _struct.unpack("!I", read_n(4))
+    return read_n(ln)
+
+
+def _sink_readings() -> int:
+    return obs.snapshot().get("fleet.complete.sink", {"n": 0})["n"]
+
+
+@pytest.fixture
+def toy_server():
+    """One np toy tenant, `max_batch` 256, behind a background server; a
+    short deadline flushes a part batch soon."""
+    cc = _toy_classifier()
+    spec = TenantSpec(name="t", backend="np", max_batch=256,
+                      deadline_ms=250.0,
+                      program=CircuitProgram.from_classifier(cc,
+                                                             backend="np"))
+    fleet = ClassifierFleet([spec], warmup=False)
+    server = FleetServer(fleet)
+    address = server.start_background()
+    yield address, fleet, CircuitProgram.from_classifier(cc).predict
+    server.stop()
+    fleet.shutdown(drain=True)
+
+
+def _submit_frame_raw(address, x, version, first_rid=1000):
+    """Send one SUBMIT_BATCH frame on a raw connection at `version` and
+    read back every answer frame until each row has one: returns the
+    req_ids sent and the `(message, payload)` of each answer."""
+    import socket as _socket
+
+    rids = np.arange(first_rid, first_rid + len(x), dtype=np.uint64)
+    frames = []
+    with _socket.create_connection(address, timeout=60) as s:
+        s.sendall(P.encode_hello(version))
+        welcome = P.decode_message(_read_frame(s))
+        assert welcome.type == P.MSG_WELCOME and welcome.version == version
+        s.sendall(P.encode_submit_batch(rids, "t", x))
+        answered = 0
+        while answered < len(x):
+            payload = _read_frame(s)
+            msg = P.decode_message(payload)
+            frames.append((msg, payload))
+            answered += (len(msg.req_ids) if msg.type == P.MSG_RESULT_BATCH
+                         else 1)
+    return rids, frames
+
+
+def test_frame_answers_in_one_result_batch_and_counts_the_sink(toy_server):
+    """A 256-row SUBMIT_BATCH served by one dispatch comes back as ONE
+    RESULT_BATCH frame (one sink call, one queue item) with the offline
+    labels, and `fleet.complete.sink` counts its 256 readings."""
+    address, _, ref = toy_server
+    x = np.random.default_rng(59).random((256, 9))
+    before = _sink_readings()
+    rids, frames = _submit_frame_raw(address, x, P.PROTOCOL_VERSION)
+    assert [m.type for m, _ in frames] == [P.MSG_RESULT_BATCH]
+    (msg, _), = frames
+    np.testing.assert_array_equal(msg.req_ids, rids)
+    np.testing.assert_array_equal(msg.labels, ref(x))
+    assert np.all(msg.latencies_ms > 0)
+    assert _sink_readings() - before == 256
+
+
+def test_v1_connection_gets_one_result_frame_a_reading(toy_server):
+    """Held to protocol v1, a frame's completions still leave as one
+    RESULT frame a reading, with the offline labels."""
+    address, _, ref = toy_server
+    x = np.random.default_rng(61).random((40, 9))
+    rids, frames = _submit_frame_raw(address, x, 1)
+    assert {m.type for m, _ in frames} == {P.MSG_RESULT}
+    got = {m.req_id: m.label for m, _ in frames}
+    assert sorted(got) == rids.tolist()
+    want = ref(x)
+    assert [got[int(r)] for r in rids] == [int(v) for v in want]
+    for m, payload in frames:   # byte for byte the v1 RESULT encoding
+        assert P.frame(payload) == P.encode_result(m.req_id, m.label,
+                                                   m.latency_ms)
+
+
+def test_dispatch_failure_answers_every_frame_row_with_error(toy_server):
+    """Every row of a frame whose dispatch fails gets an ERROR frame;
+    none is left hanging."""
+    from repro.serve.client import FleetClientError
+
+    address, fleet, _ = toy_server
+
+    def boom(x):
+        raise RuntimeError("device lost")
+
+    for rep in fleet._tenant("t").pool.replicas:
+        rep.engine.classify_batch = boom
+    x = np.random.default_rng(67).random((256, 9))
+    with FleetClient(*address) as client:
+        handles = client.submit_many("t", x)
+        for h in handles:
+            with pytest.raises(FleetClientError, match="device lost"):
+                h.result(60.0)
+
+
+def test_unary_submit_never_engages_the_frame_sink(toy_server):
+    """Unary SUBMIT completes per request: `fleet.complete.sink` stays
+    where it was, and the labels are the offline ones."""
+    address, _, ref = toy_server
+    x = np.random.default_rng(71).random((12, 9))
+    before = _sink_readings()
+    with FleetClient(*address, protocol_version=1) as client:
+        pending = [client.submit("t", row) for row in x]
+        got = [p.result(60.0) for p in pending]
+    assert got == [int(v) for v in ref(x)]
+    assert _sink_readings() == before
