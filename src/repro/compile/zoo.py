@@ -7,8 +7,9 @@ on the archive's best-accuracy chromosome — and emits Verilog + EGFET
 report + servable program bundle into one shared emit directory whose
 ``fleet.json`` indexes every tenant.  The point is scale-testing the
 serving side: a zoo directory is exactly what ``python -m repro.serve
---emit-dir <zoo> --megakernel`` wants for multi-tenant megakernel
-dispatch.
+--emit-dir <zoo> --backend pallas --megakernel`` wants for multi-tenant
+megakernel dispatch (the megakernel fuses pallas tenants only, and the
+serve CLI's default backend is swar).
 
 Incremental by construction: every manifest row is stamped with the
 entry's content fingerprint (sha256 over the full recipe), and a rebuild
@@ -304,7 +305,7 @@ def main(argv=None) -> None:
           f"cached {len(report['cached'])} in {report['build_s']:.1f}s "
           f"-> {report['manifest']}")
     print(f"[zoo] serve it: python -m repro.serve --emit-dir "
-          f"{args.emit_dir} --megakernel")
+          f"{args.emit_dir} --backend pallas --megakernel")
     if args.out:
         Path(args.out).parent.mkdir(parents=True, exist_ok=True)
         Path(args.out).write_text(json.dumps(report, indent=2, sort_keys=True)

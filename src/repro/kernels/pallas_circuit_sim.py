@@ -9,10 +9,15 @@ as a Pallas kernel instead of the `lax.scan` SWAR twin in
   * `fused_eval_uint` — gate walk, output-word extraction and LSB-first
     integer decode in ONE `pallas_call`: the value plane never leaves
     VMEM and each grid cell writes its decoded int32 tile directly;
-  * `fleet_eval_words` — the **multi-program megakernel**: T tenants'
-    plan tables padded to a common gate budget, grid over
-    (tenant x word-tile), so a serving fleet evaluates its whole manifest
-    in one launch instead of per-tenant batches.
+  * `fleet_walk` — the **multi-program megakernel**: a `FleetTable`
+    holds every tenant's plan padded to a common gate budget, on the
+    device, built once a manifest generation (`fleet_table`); a launch
+    passes only the due tenants' slots (scalar-prefetched, so each grid
+    row reads its tenant's plan block straight from the table) and their
+    word planes, grid over (slot x word-tile), and each slot walks only
+    its own gates.  Slot counts are padded to the table's few launch
+    shapes (`launch_buckets`), so a warmed fleet never compiles again.
+    `fleet_eval_words` is the one-shot form over ad-hoc plans.
 
 Grid layout is (population rows, word tiles).  Each program instance
 owns one population row (one genome, or one tenant) and a `bw`-wide tile
@@ -49,6 +54,8 @@ any other platform it refuses to run (`_interpret`).
 """
 from __future__ import annotations
 
+import time
+from dataclasses import dataclass
 from functools import partial
 
 import jax
@@ -88,11 +95,13 @@ def _word_tile(W: int, block_words: int | None) -> int:
 
 
 def _kernel(plan_ref, outputs_ref, words_ref, out_ref, vals_ref, *,
-            n_inputs: int, n_gates: int, n_out: int, decode: bool):
+            n_inputs: int, n_gates: int, n_out: int, decode: bool,
+            walk=None):
     # plan_ref (1, 6, G) and outputs_ref (1, 1, n_out) int32 in SMEM;
     # words_ref (n_inputs, bw) shared or (1, n_inputs, bw) per-row;
     # vals_ref (n_inputs + G, bw) int32 VMEM scratch; out_ref (1, 32, bw)
-    # decoded or (1, n_out, bw) output words.
+    # decoded or (1, n_out, bw) output words.  `walk`, a traced scalar,
+    # stops the walk after the row's own gates (the rest stay zero).
     bw = vals_ref.shape[1]
     words = words_ref[0] if len(words_ref.shape) == 3 else words_ref[...]
     vals_ref[pl.ds(0, n_inputs), :] = words
@@ -107,7 +116,7 @@ def _kernel(plan_ref, outputs_ref, words_ref, out_ref, vals_ref, *,
         vals_ref[pl.ds(n_inputs + g, 1), :] = r
         return carry
 
-    jax.lax.fori_loop(0, n_gates, body, 0)
+    jax.lax.fori_loop(0, n_gates if walk is None else walk, body, 0)
     if decode:
         # LSB-first: vector s of word w is bit (s % 32), so tile row s
         # collects bit s of every word, one output bit per integer bit
@@ -244,9 +253,223 @@ population_eval_uint = fused_eval_uint
 
 
 # ---------------------------------------------------------------------------
-# Multi-program megakernel: T tenants' plans padded to one gate budget,
-# grid over (tenant x word-tile), one launch for the whole manifest.
+# Multi-program megakernel: every tenant's plan padded to one gate budget in
+# a device-resident table; a launch names the due tenants' slots.
 # ---------------------------------------------------------------------------
+def launch_buckets(n_rows: int) -> tuple[int, ...]:
+    """Slot counts a table of `n_rows` tenants launches at: the powers of
+    two below `n_rows`, then `n_rows` (1, 2, 4, 8, 16, 20 for 20)."""
+    out, b = [], 1
+    while b < n_rows:
+        out.append(b)
+        b *= 2
+    return tuple(out) + (n_rows,)
+
+
+@dataclass(frozen=True)
+class FleetTable:
+    """The padded plans of every program a fused launch may carry.
+
+    Device arrays: `plan` (N + 1, 6, G) and `outputs` (N + 1, 1, n_out)
+    int32, `gates` (N + 1,) int32, each row's own gate count.  Row N is
+    empty (no inputs, no gates, outputs on the zero node): a launch's
+    pad slots point there.  Program rows take `n_inputs` input rows (the
+    largest) and word planes up to `words` wide (the widest), walked in
+    tiles of `block_words`; `buckets` are the slot counts a launch is
+    padded to, one launch shape each.  `row_inputs` and `row_gates` are
+    the host copies of each row's own sizes."""
+
+    plan: jax.Array
+    outputs: jax.Array
+    gates: jax.Array
+    row_inputs: tuple[int, ...]
+    row_gates: tuple[int, ...]
+    n_inputs: int
+    words: int
+    block_words: int
+    buckets: tuple[int, ...]
+    device: object
+
+    @property
+    def empty_row(self) -> int:
+        return len(self.row_gates) - 1
+
+    @property
+    def padded_words(self) -> int:
+        return self.words + (-self.words) % self.block_words
+
+    def bucket(self, n_slots: int) -> int:
+        """The launch shape a launch of `n_slots` slots runs at."""
+        for b in self.buckets:
+            if b >= n_slots:
+                return b
+        raise ValueError(f"{n_slots} slots exceed the table's "
+                         f"{self.buckets[-1]} rows")
+
+    def gate_steps(self, slots, words_used) -> tuple[int, int]:
+        """(real, walked) gate steps of a launch, one a gate and a word:
+        real over the words holding readings, walked over every word a
+        slot's grid row covers.  Pad slots walk no gates."""
+        wp = self.padded_words
+        real = walked = 0
+        for s, w in zip(slots, words_used):
+            g = self.row_gates[s]
+            real += g * w
+            walked += g * wp
+        return real, walked
+
+
+def fleet_table(plans, words: int, *, block_words: int | None = None,
+                buckets: tuple[int, ...] | None = None) -> FleetTable:
+    """Pad `plans`, one `(op, in0, in1, outputs, n_inputs)` tuple a
+    program (1-D or `(1, G)` rows), into a `FleetTable` on the default
+    device for word planes up to `words` wide, in word tiles of
+    `block_words` (see `_word_tile`).
+
+    Gate nodes shift past the largest input count, every row gets the
+    common gate budget (largest + 1: the last node is never written, so
+    it stays zero) and padded output taps point at that zero node, so no
+    pad can reach any program's decoded integers."""
+    from repro.hw.egfet import Gate
+
+    if not plans:
+        raise ValueError("a fleet table needs at least one plan")
+    with obs.span("dispatch.plan"):
+        norm = []
+        for op, in0, in1, outputs, n_in in plans:
+            norm.append((np.asarray(op, dtype=np.int64).reshape(-1),
+                         np.asarray(in0, dtype=np.int32).reshape(-1),
+                         np.asarray(in1, dtype=np.int32).reshape(-1),
+                         np.asarray(outputs, dtype=np.int32).reshape(-1),
+                         int(n_in)))
+        N = len(norm)
+        n_in_max = max(p[4] for p in norm)
+        G = max(p[0].shape[0] for p in norm) + 1
+        n_out = max(p[3].shape[0] for p in norm)
+        zero_node = n_in_max + G - 1
+        op_t = np.full((N + 1, G), int(Gate.CONST0), dtype=np.int64)
+        in0_t = np.zeros((N + 1, G), dtype=np.int32)
+        in1_t = np.zeros((N + 1, G), dtype=np.int32)
+        out_t = np.full((N + 1, 1, n_out), zero_node, dtype=np.int32)
+        gates = np.zeros(N + 1, dtype=np.int32)
+
+        def remap(idx: np.ndarray, n_in: int) -> np.ndarray:
+            # program numbering: inputs 0..n_in-1, gates n_in.. — shift
+            # the gate nodes past the padded input rows
+            return np.where(idx >= n_in, idx + (n_in_max - n_in), idx)
+
+        for t, (op, in0, in1, outputs, n_in) in enumerate(norm):
+            g = op.shape[0]
+            op_t[t, :g] = op
+            in0_t[t, :g] = remap(in0, n_in)
+            in1_t[t, :g] = remap(in1, n_in)
+            out_t[t, 0, : outputs.shape[0]] = remap(outputs, n_in)
+            gates[t] = g
+        plan = _plan_table(op_t, in0_t, in1_t)
+    device = jax.devices()[0]
+    with obs.span("dispatch.h2d"):
+        plan, out_t, gates_d = (jax.device_put(a, device)
+                                for a in (plan, out_t, gates))
+    return FleetTable(plan=plan, outputs=out_t, gates=gates_d,
+                      row_inputs=tuple(p[4] for p in norm) + (0,),
+                      row_gates=tuple(int(g) for g in gates),
+                      n_inputs=n_in_max, words=int(words),
+                      block_words=_word_tile(int(words), block_words),
+                      buckets=buckets or launch_buckets(N), device=device)
+
+
+def _fleet_kernel(slots_ref, gates_ref, *refs, **kw):
+    _kernel(*refs, walk=gates_ref[slots_ref[pl.program_id(0)]], **kw)
+
+
+@partial(jax.jit, static_argnames=("n_inputs", "block_words", "interpret"))
+def _fleet_walk(slots, gates, plan, outputs, words32, *, n_inputs: int,
+                block_words: int, interpret: bool):
+    """`slots` (T,) rows of the table `plan` (N, 6, G) / `outputs`
+    (N, 1, n_out) / `gates` (N,), and `words32` (T, n_inputs, Wp) uint32,
+    Wp a multiple of `block_words` -> (T, Wp*32) int32 decoded integers.
+    The slots and gate counts are prefetched into SMEM; each grid row's
+    plan block is its slot's row of the table."""
+    G = plan.shape[2]
+    n_out = outputs.shape[2]
+    T, _, Wp = words32.shape
+    bw = block_words
+    words = jax.lax.bitcast_convert_type(words32, jnp.int32)
+    with jax.named_scope(FLEET_WALK):
+        out = pl.pallas_call(
+            partial(_fleet_kernel, n_inputs=n_inputs, n_gates=G,
+                    n_out=n_out, decode=True),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(T, Wp // bw),
+                in_specs=[
+                    pl.BlockSpec((1, 6, G), lambda p, w, s, g: (s[p], 0, 0),
+                                 memory_space=pltpu.SMEM),
+                    pl.BlockSpec((1, 1, n_out),
+                                 lambda p, w, s, g: (s[p], 0, 0),
+                                 memory_space=pltpu.SMEM),
+                    pl.BlockSpec((1, n_inputs, bw),
+                                 lambda p, w, s, g: (p, 0, w))],
+                out_specs=pl.BlockSpec((1, 32, bw),
+                                       lambda p, w, s, g: (p, 0, w)),
+                scratch_shapes=[pltpu.VMEM((n_inputs + G, bw), jnp.int32)]),
+            out_shape=jax.ShapeDtypeStruct((T, 32, Wp), jnp.int32),
+            interpret=interpret,
+            name=FLEET_WALK,
+        )(slots, gates, plan, outputs, words)
+    return out.transpose(0, 2, 1).reshape(T, Wp * 32)
+
+
+def fleet_walk(table: FleetTable, slots, words_list) -> list[np.ndarray]:
+    """Evaluate the programs in `slots` (rows of `table`) over their
+    `(n_inputs_t, W_t)` uint32 word planes in ONE launch, padded to the
+    table's next launch shape.  Returns one `(W_t * 32,)` int32 array a
+    slot, bit-identical to running each program through
+    `fused_eval_uint` on its own."""
+    slots = [int(s) for s in slots]
+    if len(slots) != len(words_list):
+        raise ValueError(f"{len(slots)} slots but {len(words_list)} word "
+                         "planes")
+    T = table.bucket(len(slots))
+    Wp = table.padded_words
+    with obs.span("dispatch.pack"):      # the launch's slots and planes
+        slot_arr = np.full(T, table.empty_row, dtype=np.int32)
+        slot_arr[: len(slots)] = slots
+        words_t = np.zeros((T, table.n_inputs, Wp), dtype=np.uint32)
+        widths = []
+        for i, (s, w) in enumerate(zip(slots, words_list)):
+            w = np.asarray(w, dtype=np.uint32)
+            if (w.ndim != 2 or w.shape[0] != table.row_inputs[s]
+                    or w.shape[1] > table.words):
+                raise ValueError(f"slot {s}: word plane {w.shape} does not "
+                                 f"fit ({table.row_inputs[s]}, <= "
+                                 f"{table.words})")
+            words_t[i, : w.shape[0], : w.shape[1]] = w
+            widths.append(w.shape[1])
+    with obs.span("dispatch.h2d"):
+        slot_d, words_d = (jax.device_put(a, table.device)
+                           for a in (slot_arr, words_t))
+    with obs.span("dispatch.launch"):
+        out = _fleet_walk(slot_d, table.gates, table.plan, table.outputs,
+                          words_d, n_inputs=table.n_inputs,
+                          block_words=table.block_words,
+                          interpret=_interpret())
+    with obs.span("dispatch.fetch"):
+        out = np.asarray(out)
+    return [out[i, : widths[i] * 32] for i in range(len(slots))]
+
+
+def warm_fleet_table(table: FleetTable) -> float:
+    """Compile every launch shape of `table` (pad slots, empty planes) and
+    return the wall seconds of one warm launch at its largest shape."""
+    dt = 0.0
+    for b in table.buckets + (table.buckets[-1],):
+        t0 = time.perf_counter()
+        fleet_walk(table, [table.empty_row] * b,
+                   [np.zeros((0, 0), np.uint32)] * b)
+        dt = time.perf_counter() - t0
+    return dt
+
+
 def fleet_eval_words(plans, words_list, *,
                      block_words: int | None = None) -> list[np.ndarray]:
     """Evaluate T single-program circuits over T word planes in ONE launch.
@@ -254,66 +477,24 @@ def fleet_eval_words(plans, words_list, *,
     `plans` is a list of `(op, in0, in1, outputs, n_inputs)` tuples —
     each a single program's plan (arrays may be `(G,)`/`(n_out,)` 1-D or
     `(1, G)`/`(1, n_out)` rows); `words_list` holds each tenant's packed
-    `(n_inputs_t, W_t)` uint32 word plane.  Plans are padded to a common
-    gate budget and feature count (node indices remapped so gate nodes
-    land after the padded input rows), every tenant gets one trailing
-    CONST0 pad gate, and padded output taps point at that known-zero node
-    — so neither the gate-budget pad, the feature pad, the word pad nor
-    the output pad can leak into any tenant's decoded integers.  Returns
-    one `(W_t * 32,)` int32 array per tenant, bit-identical to running
-    each plan through `fused_eval_uint` on its own.
+    `(n_inputs_t, W_t)` uint32 word plane.  The plans become a one-shot
+    `FleetTable` and run as one `fleet_walk`.  Returns one `(W_t * 32,)`
+    int32 array per tenant, bit-identical to running each plan through
+    `fused_eval_uint` on its own.
     """
-    from repro.hw.egfet import Gate
-
     if not plans:
         return []
     if len(plans) != len(words_list):
         raise ValueError(f"{len(plans)} plans but {len(words_list)} word "
                          "planes")
-    norm = []
-    for i, (op, in0, in1, outputs, n_in) in enumerate(plans):
-        op = np.asarray(op, dtype=np.int16).reshape(-1)
-        in0 = np.asarray(in0, dtype=np.int32).reshape(-1)
-        in1 = np.asarray(in1, dtype=np.int32).reshape(-1)
-        outputs = np.asarray(outputs, dtype=np.int32).reshape(-1)
-        w = np.ascontiguousarray(words_list[i], dtype=np.uint32)
+    words_list = [np.asarray(w, dtype=np.uint32) for w in words_list]
+    for i, ((*_, n_in), w) in enumerate(zip(plans, words_list)):
         if w.ndim != 2 or w.shape[0] != n_in:
             raise ValueError(f"plan {i}: word plane {w.shape} does not "
                              f"match n_inputs={n_in}")
-        norm.append((op, in0, in1, outputs, int(n_in), w))
-
-    with obs.span("dispatch.plan"):      # the padded manifest plan
-        T = len(norm)
-        n_in_max = max(p[4] for p in norm)
-        G_max = max(p[0].shape[0] for p in norm) + 1  # +1: zero node
-        n_out_max = max(p[3].shape[0] for p in norm)
-        W_list = [p[5].shape[1] for p in norm]
-        W_max = max(W_list)
-        if W_max == 0:
-            return [np.zeros(0, dtype=np.int32) for _ in norm]
-
-        zero_node = n_in_max + G_max - 1    # the trailing CONST0 pad gate
-        op_t = np.full((T, G_max), int(Gate.CONST0), dtype=np.int16)
-        in0_t = np.zeros((T, G_max), dtype=np.int32)
-        in1_t = np.zeros((T, G_max), dtype=np.int32)
-        out_t = np.full((T, n_out_max), zero_node, dtype=np.int32)
-        words_t = np.zeros((T, n_in_max, W_max), dtype=np.uint32)
-
-        def remap(idx: np.ndarray, n_in: int) -> np.ndarray:
-            # tenant node numbering: inputs 0..n_in-1, gates n_in.. —
-            # shift the gate nodes past the padded input rows
-            return np.where(idx >= n_in, idx + (n_in_max - n_in), idx)
-
-        for t, (op, in0, in1, outputs, n_in, w) in enumerate(norm):
-            G = op.shape[0]
-            op_t[t, :G] = op
-            in0_t[t, :G] = remap(in0, n_in)
-            in1_t[t, :G] = remap(in1, n_in)
-            out_t[t, : outputs.shape[0]] = remap(outputs, n_in)
-            words_t[t, :n_in, : w.shape[1]] = w
-
-    out = _run(op_t, in0_t, in1_t, out_t, words_t, n_in_max,
-               block_words=block_words, decode=True, name=FLEET_WALK)
-    with obs.span("dispatch.fetch"):
-        out = np.asarray(out)
-    return [out[t, : W_list[t] * 32] for t in range(T)]
+    W_max = max(w.shape[1] for w in words_list)
+    if W_max == 0:
+        return [np.zeros(0, dtype=np.int32) for _ in plans]
+    table = fleet_table(plans, W_max, block_words=block_words,
+                        buckets=(len(plans),))
+    return fleet_walk(table, range(len(plans)), words_list)

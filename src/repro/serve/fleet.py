@@ -269,6 +269,15 @@ class _Tenant:
         return self.pool.replicas[0].engine
 
 
+@dataclass(frozen=True)
+class _FusedTable:
+    """A manifest generation's fused-launch plan table
+    (`kernels.pallas_circuit_sim.FleetTable`) and each tenant's row."""
+
+    plans: object
+    slots: dict
+
+
 class _BackendWorker(threading.Thread):
     """One scheduler thread per execution backend.
 
@@ -287,8 +296,10 @@ class _BackendWorker(threading.Thread):
         self.backend = backend
         self.tenants = tenants
         # megakernel mode: every due pallas tenant rides ONE multi-program
-        # kernel launch per scheduler pass instead of per-tenant dispatches
+        # kernel launch per scheduler pass instead of per-tenant dispatches,
+        # over the table of this manifest generation's plans
         self.fused = bool(fleet.megakernel) and backend == "pallas"
+        self.fused_table: _FusedTable | None = None
         self.cond = threading.Condition()
         self.stop = False          # set under cond; drain-all then exit
         self.kick = False          # flush(): treat every queue as due
@@ -383,6 +394,7 @@ class _BackendWorker(threading.Thread):
                               else [t for t in (self._pick(now),)
                                     if t is not None])
                     if picked:
+                        fused = self.fused_table
                         jobs = []
                         for tenant in picked:
                             batch = tenant.batcher.pop_batch()
@@ -398,7 +410,7 @@ class _BackendWorker(threading.Thread):
                     self.cond.wait(self._wait_s(now))
                 ex = self._ensure_executor()
             if self.fused:
-                ex.submit(self._run_dispatch_fused, jobs)
+                ex.submit(self._run_dispatch_fused, jobs, fused)
             else:
                 ex.submit(self._run_dispatch, *jobs[0])
 
@@ -417,10 +429,10 @@ class _BackendWorker(threading.Thread):
                 self._reap_retired()
                 self.cond.notify_all()
 
-    def _run_dispatch_fused(self, jobs: list) -> None:
+    def _run_dispatch_fused(self, jobs: list, fused: _FusedTable) -> None:
         ok = False
         try:
-            ok = self.fleet._dispatch_fused(jobs)
+            ok = self.fleet._dispatch_fused(jobs, fused)
         finally:
             with self.cond:
                 for tenant, replica, batch in jobs:
@@ -468,6 +480,9 @@ class ClassifierFleet:
         self.megakernel_block_words = megakernel_block_words
         self._megakernel_launches = 0       # fused multi-tenant launches
         self._megakernel_peak_tenants = 0   # most tenants in one launch
+        self._megakernel_tenants = 0        # tenants over all launches
+        self._megakernel_gates = [0, 0]     # real, walked gate steps
+        self._megakernel_lock = threading.Lock()
         self._worker_hosts: dict[str, WorkerHost] = {}  # backend -> host
         self._worker_key_seq = 0
         self._autoscaler = Autoscaler(autoscale) if autoscale else None
@@ -482,6 +497,9 @@ class ClassifierFleet:
             by_backend.setdefault(t.spec.backend, []).append(t)
         self._workers = {b: _BackendWorker(self, b, ts)
                          for b, ts in sorted(by_backend.items())}
+        for w in self._workers.values():
+            if w.fused:
+                w.fused_table = self._fused_table(w.tenants, fresh=w.tenants)
         self._uid_lock = threading.Lock()
         self._next_uid = 0
         self._next_batch_uid = 0        # one per submit_many frame
@@ -528,16 +546,60 @@ class ClassifierFleet:
                 est = max(1e-4, host.warmup(t.worker_key))
                 t.est_dispatch_s = est
                 t.last_dispatch_s = est
-        elif self.warmup_on_load:
+        elif self.warmup_on_load and not self._fuses(spec):
             # every replica: each is pinned to its own device, so each has
             # its own executable to compile — a cold replica would pay jit
-            # inside its first deadline-bound batch
+            # inside its first deadline-bound batch (a fused tenant's
+            # launches warm with its worker's table instead)
             est = 1e-4
             for rep in t.pool.replicas:
                 est = max(est, rep.engine.warmup())
             t.est_dispatch_s = est
             t.last_dispatch_s = est
         return t
+
+    def _fuses(self, spec: TenantSpec) -> bool:
+        """Whether `spec`'s batches ride the fused megakernel launch."""
+        return self.megakernel and spec.backend == "pallas"
+
+    def _fused_table(self, tenants: list[_Tenant],
+                     fresh=()) -> _FusedTable | None:
+        """One manifest generation's fused-launch table over `tenants`,
+        built on the host and uploaded once.  With warm-up on load every
+        launch shape compiles here, and the `fresh` tenants' dispatch
+        estimates start from a warm launch at the largest shape."""
+        if not tenants:
+            return None
+        from repro.kernels import pallas_circuit_sim as PS
+
+        plans = PS.fleet_table(
+            [t.engine.program.plan() for t in tenants],
+            max(-(-t.spec.max_batch // 32) for t in tenants),
+            block_words=self.megakernel_block_words)
+        if self.warmup_on_load:
+            est = max(1e-4, PS.warm_fleet_table(plans))
+            for t in fresh:
+                t.est_dispatch_s = t.last_dispatch_s = est
+        return _FusedTable(plans, {t: i for i, t in enumerate(tenants)})
+
+    def _next_fused_table(self, worker: _BackendWorker,
+                          fresh=()) -> _FusedTable | None:
+        """The table a fusing `worker` needs once `fresh` joins its
+        tenants, built outside its lock (the caller installs it with the
+        tenants); None for a worker that does not fuse."""
+        if not worker.fused:
+            return None
+        with worker.cond:
+            tenants = list(worker.tenants)
+        return self._fused_table(tenants + list(fresh), fresh=fresh)
+
+    def _shrink_fused_table(self, worker: _BackendWorker) -> None:
+        """Drop drained tenants' rows: a new table over what is left."""
+        if worker.fused:
+            with self._admin_lock:
+                table = self._next_fused_table(worker)
+                with worker.cond:
+                    worker.fused_table = table
 
     # -- construction -------------------------------------------------------
     @classmethod
@@ -1049,46 +1111,46 @@ class ClassifierFleet:
                                        f"{type(exc).__name__}: {exc}")
             i = j
 
-    def _dispatch_fused(self, jobs: list) -> bool:
+    def _dispatch_fused(self, jobs: list, fused: _FusedTable) -> bool:
         """Serve MANY tenants' popped batches in one megakernel launch.
 
         `jobs` is `[(tenant, replica, entries), ...]` — every due pallas
-        tenant of this scheduler pass.  Each tenant's batch is binarized
-        with its own ABC thresholds, padded to its engine's compiled
-        batch shape (so the fused kernel sees stable word widths and the
-        jit cache stays warm), bit-packed, and the whole manifest goes
-        through `kernels.dispatch.fleet_eval_words` as ONE launch.
-        Per-tenant accounting mirrors `_dispatch`: every tenant is
-        charged the full launch wall time (that IS the latency its batch
-        paid), the fleet-level batch sample is recorded once per launch,
-        and shadows stay out of fleet stats and the error log.  A launch
-        failure fails every request of every job — the whole launch is
-        the unit of execution.
+        tenant of this scheduler pass; `fused` is the worker's plan table
+        of the manifest generation they were picked under.  Each tenant's
+        batch is binarized with its own ABC thresholds, padded to its
+        engine's compiled batch shape, bit-packed, and the launch passes
+        only the tenants' rows of the table and their word planes to
+        `kernels.pallas_circuit_sim.fleet_walk` (one of the table's few
+        launch shapes, all compiled at warm-up).  Per-tenant accounting
+        mirrors `_dispatch`: every tenant is charged the full launch wall
+        time (that IS the latency its batch paid), the fleet-level batch
+        sample is recorded once per launch, and shadows stay out of fleet
+        stats and the error log.  A launch failure fails every request of
+        every job — the whole launch is the unit of execution.
         """
         now = self._clock()
         for _, _, entries in jobs:
             obs.add("fleet.queue_wait", now - entries[0].t_submit)
         with obs.span("fleet.dispatch", tenants=len(jobs)):
-            return self._serve_fused(jobs)
+            return self._serve_fused(jobs, fused)
 
-    def _serve_fused(self, jobs: list) -> bool:
-        from repro.kernels import dispatch as D
+    def _serve_fused(self, jobs: list, fused: _FusedTable) -> bool:
+        from repro.kernels import pallas_circuit_sim as PS
 
         prepared = []
         try:
-            plans, words_list = [], []
+            slots, words_list, used = [], [], []
             for tenant, replica, entries in jobs:
                 reqs = [e.item for e in entries]
                 with obs.span("dispatch.gather"):
                     x = self._gather_batch(reqs)
                 words32, B = replica.engine.prepare_packed_batch(x)
-                plans.append(replica.engine.program.plan())
+                slots.append(fused.slots[tenant])
                 words_list.append(words32)
+                used.append(-(-B // 32))
                 prepared.append((tenant, replica, reqs, B))
             t0 = self._clock()
-            outs = D.fleet_eval_words(
-                plans, words_list, backend="pallas",
-                block_words=self.megakernel_block_words)
+            outs = PS.fleet_walk(fused.plans, slots, words_list)
             dt = self._clock() - t0
         except Exception as exc:        # complete exceptionally, never hang
             msg = f"megakernel: {type(exc).__name__}: {exc}"
@@ -1097,14 +1159,23 @@ class ClassifierFleet:
                     self.errors.append(f"{tenant.name}: {msg}")
                 self._finish([e.item for e in entries], error=msg)
             return False
+        real, walked = fused.plans.gate_steps(slots, used)
+        obs.add("fleet.fused", dt)
+        obs.add("fleet.fused.tenants", 0.0, n=len(jobs))
+        obs.add("fleet.fused.gates_real", 0.0, n=real)
+        obs.add("fleet.fused.gates_walked", 0.0, n=walked)
         with obs.span("fleet.complete"):
             live_readings = sum(len(reqs) for t, _, reqs, _ in prepared
                                 if t.shadow_of is None)
             if live_readings:
                 self.stats.record(live_readings, dt)   # one launch, one batch
-            self._megakernel_launches += 1
-            self._megakernel_peak_tenants = max(
-                self._megakernel_peak_tenants, len(jobs))
+            with self._megakernel_lock:
+                self._megakernel_launches += 1
+                self._megakernel_peak_tenants = max(
+                    self._megakernel_peak_tenants, len(jobs))
+                self._megakernel_tenants += len(jobs)
+                self._megakernel_gates[0] += real
+                self._megakernel_gates[1] += walked
             for (tenant, replica, reqs, B), out in zip(prepared, outs):
                 labels = np.asarray(out[:B], dtype=np.int32)
                 tenant.est_dispatch_s = (0.7 * tenant.est_dispatch_s
@@ -1154,7 +1225,10 @@ class ClassifierFleet:
                 self._workers[spec.backend] = worker
                 if self._started:
                     worker.start()
+            table = self._next_fused_table(worker, [t])
             with worker.cond:
+                if worker.fused:
+                    worker.fused_table = table
                 self._shadows[of] = t
                 worker.tenants.append(t)
                 worker.cond.notify_all()
@@ -1193,6 +1267,7 @@ class ClassifierFleet:
                         f"shadow of {of!r} still draining after {timeout}s "
                         f"({len(t.batcher)} queued)")
                 worker.cond.wait(min(left, 0.05))
+        self._shrink_fused_table(worker)
         return t.comparator.summary()
 
     # -- hot reload ----------------------------------------------------------
@@ -1214,7 +1289,10 @@ class ClassifierFleet:
                 self._workers[spec.backend] = worker
                 if self._started:
                     worker.start()
+            table = self._next_fused_table(worker, [t])
             with worker.cond:
+                if worker.fused:
+                    worker.fused_table = table
                 self._tenants[spec.name] = t
                 worker.tenants.append(t)
                 worker.cond.notify_all()
@@ -1240,6 +1318,7 @@ class ClassifierFleet:
                 self._workers[spec.backend] = new_worker
                 if self._started:
                     new_worker.start()
+            table = self._next_fused_table(new_worker, [new])
             first, second = ((old_worker, new_worker)
                              if id(old_worker) <= id(new_worker)
                              else (new_worker, old_worker))
@@ -1253,6 +1332,8 @@ class ClassifierFleet:
                     if compatible:
                         new.batcher.adopt(moved)
                     self._tenants[spec.name] = new
+                    if new_worker.fused:
+                        new_worker.fused_table = table
                     new_worker.tenants.append(new)
                     old.retiring = True
                     old_worker.cond.notify_all()
@@ -1280,6 +1361,7 @@ class ClassifierFleet:
                         f"tenant {name!r} still draining after {timeout}s "
                         f"({len(t.batcher)} queued)")
                 worker.cond.wait(min(left, 0.05))
+        self._shrink_fused_table(worker)
 
     def sync_manifest(self) -> dict:
         """Reconcile live tenants with the emit dir's current `fleet.json`.
@@ -1396,7 +1478,8 @@ class ClassifierFleet:
                                stats_window=self.stats_window)
             # in worker mode the subprocess engines are already warm; the
             # fleet-side replica is only a concurrency token + ledger
-            if self.warmup_on_load and t.worker_key is None:
+            if (self.warmup_on_load and t.worker_key is None
+                    and not self._fuses(t.spec)):
                 rep.engine.warmup()
             fresh.append(rep)
         with worker.cond:
@@ -1539,9 +1622,20 @@ class ClassifierFleet:
                 "spans": obs.snapshot(),
             }
             if self.megakernel:
+                with self._megakernel_lock:
+                    launches = self._megakernel_launches
+                    tenants_sum = self._megakernel_tenants
+                    real, walked = self._megakernel_gates
+                worker = self._workers.get("pallas")
+                fused = worker.fused_table if worker is not None else None
                 out["megakernel"] = {
-                    "launches": self._megakernel_launches,
+                    "launches": launches,
                     "peak_tenants_per_launch": self._megakernel_peak_tenants,
+                    "mean_tenants_per_launch": (tenants_sum / launches
+                                                if launches else 0.0),
+                    "pad_share": 1.0 - real / walked if walked else 0.0,
+                    "launch_shapes": (list(fused.plans.buckets)
+                                      if fused is not None else []),
                     "block_words": self.megakernel_block_words,
                 }
         if self._worker_hosts:

@@ -100,7 +100,7 @@ def test_no_interpret_override_on_the_serving_path():
     for fn in (D.population_eval_uint, D.population_eval_pop,
                D.program_eval_words, D.fleet_eval_words,
                PS.fused_eval_uint, PS.simulate_population,
-               PS.fleet_eval_words):
+               PS.fleet_eval_words, PS.fleet_table, PS.fleet_walk):
         assert "interpret" not in inspect.signature(fn).parameters, fn
     assert "pallas_interpret" not in CircuitProgram.__dataclass_fields__
 

@@ -3,8 +3,8 @@
 The TPU compiler is installed alongside JAX, so the kernels of the main
 path are compiled here for a described `v5e:2x2` topology at real
 widths: the serving shape of the largest Table-2 classifier
-(arrhythmia), the campaign's population shape, and a five-tenant fleet
-launch.  Interpret-mode tests cannot see what Mosaic refuses (unaligned
+(arrhythmia), the campaign's population shape, and fused fleet launches
+of five and twenty tenants.  Interpret-mode tests cannot see what Mosaic refuses (unaligned
 blocks, dynamic lane indexing, unsupported primitives); these can.
 Nothing runs, so nothing here says anything about results or times.
 
@@ -42,10 +42,17 @@ PALLAS_CASES = {
     # TNN campaign objective: 32 genomes, each on its own plane (cardio:
     # 1,488 training rows -> 48 words), output popcount circuits
     "evolve_population": (32, 96, 16, 5, (32, 16, 48), 48),
-    # megakernel over the five Table-2 tenants: plans padded to the
-    # largest (arrhythmia, plus the shared zero gate), per-tenant planes
+    # five per-row planes at arrhythmia's gate budget (plus a zero gate)
     "fleet_5_tenants": (5, ARR_GATES + 1, ARR_INPUTS, ARR_OUT,
                         (5, ARR_INPUTS, SERVE_WORDS), SERVE_WORDS),
+}
+
+# the fused fleet walk at a manifest's largest launch shape: name ->
+# (slots, table rows with the empty one); plans padded to arrhythmia's
+# gate budget and inputs, one 256-reading plane a slot
+FLEET_CASES = {
+    "fleet_5_tenants": (5, 6),
+    "fleet_20_tenants": (20, 21),
 }
 
 
@@ -85,6 +92,22 @@ def test_pallas_gate_walk_compiles_for_v5e(one_chip, case, decode):
     Wp = wshape[-1]
     out_bytes = P * Wp * 4 * (32 if decode else n_out)
     assert mem.output_size_in_bytes >= out_bytes
+
+
+@pytest.mark.parametrize("case", sorted(FLEET_CASES))
+def test_fleet_walk_compiles_for_v5e(one_chip, case):
+    T, N = FLEET_CASES[case]
+    G = ARR_GATES + 1
+    compiled = PS._fleet_walk.lower(
+        _sds((T,), jnp.int32, one_chip), _sds((N,), jnp.int32, one_chip),
+        _sds((N, 6, G), jnp.int32, one_chip),
+        _sds((N, 1, ARR_OUT), jnp.int32, one_chip),
+        _sds((T, ARR_INPUTS, SERVE_WORDS), jnp.uint32, one_chip),
+        n_inputs=ARR_INPUTS, block_words=SERVE_WORDS,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes >= \
+        T * SERVE_WORDS * 32 * 4
 
 
 def test_swar_scan_compiles_for_v5e_at_arrhythmia(one_chip):
