@@ -358,6 +358,16 @@ class TNNApproxProblem:
                                    for cands in self.hidden_cands]
         self._out_areas = np.array([nl.cost().area_mm2 for nl in self.out_cands])
         self._out_pop = C.NetlistPopulation.from_netlists(self.out_cands)
+        # every output neuron's inputs as one (C, nnz) gather of the hidden
+        # bits, in `_output_bits` order (w = +1 wires, then w = -1 through a
+        # NOT, the mask): approximate popcounts are not symmetric
+        w2 = self.tnn.w2t
+        cols = np.arange(w2.shape[1])
+        self._out_idx = np.array(
+            [np.concatenate([np.where(w2[:, o] == 1)[0],
+                             np.where(w2[:, o] == -1)[0]]) for o in cols],
+            dtype=np.int64).reshape(len(cols), self.tnn.out_nnz)
+        self._out_neg = (w2[self._out_idx, cols[:, None]] == -1).astype(np.uint8)
 
     # -- chromosome layout ---------------------------------------------------
     @property
@@ -412,9 +422,10 @@ class TNNApproxProblem:
         """Population-parallel objectives: (N, n_genes) int -> (N, 2).
 
         Hidden-gene bits come from the per-candidate caches via one gather;
-        every output neuron is scored for the whole population in a single
-        `NetlistPopulation` pass over per-individual packed inputs.  Matches
-        `_eval_one` (the serial reference) bit-for-bit.
+        every output neuron of every genome is scored in one launch a call:
+        the N x C candidate popcounts stack into one `NetlistPopulation` of
+        N·C rows (row n·C + o is genome n's neuron o) over per-row packed
+        inputs.  Matches `_eval_one` (the serial reference) bit-for-bit.
         """
         pop = np.asarray(pop, dtype=np.int64)
         P = pop.shape[0]
@@ -426,36 +437,34 @@ class TNNApproxProblem:
         P = pop.shape[0]
         S = self.xbin.shape[0]
         nh = len(self.hidden_idx)
-        Cc = self.tnn.w2t.shape[1]
+        Cc, nnz = self._out_idx.shape
         with obs.span("tnn.objective.gather"):
             est = np.full(P, self.fixed_cost.area_mm2)
             hbits = np.repeat(self.fixed_hbits[None], P, axis=0)  # (P, S, H)
             for g, cache in enumerate(self.hidden_bit_cache):
                 hbits[:, :, self.hidden_idx[g]] = cache[pop[:, g]]
                 est = est + self._hidden_gene_areas[g][pop[:, g]]
-            subs = []
             for o in range(Cc):
                 est = est + self._out_areas[pop[:, nh + o]]
-                subs.append(self._out_pop.take(pop[:, nh + o]))
-        scores = np.empty((P, S, Cc), dtype=np.int64)
-        for o in range(Cc):
-            col = self.tnn.w2t[:, o]
+            sub = self._out_pop.take(pop[:, nh:].reshape(-1))
+        if nnz == 0:                     # no inputs: every score is 0
+            scores = np.zeros((P, Cc, S), dtype=np.int64)
+        else:
             with obs.span("tnn.objective.pack"):
-                bits = np.concatenate([hbits[:, :, col == 1],
-                                       1 - hbits[:, :, col == -1]], axis=2)
-                if bits.shape[2] == 0:
-                    scores[:, :, o] = 0
-                    continue
-                packed = C.pack_vectors(bits)                # (P, nnz, W)
+                bits = hbits[:, :, self._out_idx] ^ self._out_neg  # (P,S,C,nnz)
+                packed = C.pack_vectors(bits.transpose(0, 2, 1, 3))
+                packed = packed.reshape(P * Cc, nnz, -1)
             with obs.span("tnn.objective.eval"):
                 if self.eval_backend == "np":
-                    scores[:, :, o] = subs[o].eval_uint(packed)[:, :S]
+                    out = sub.eval_uint(packed)
                 else:
                     from repro.kernels.dispatch import population_eval_pop
-                    scores[:, :, o] = population_eval_pop(
-                        subs[o], packed, backend=self.eval_backend)[:, :S]
+                    out = population_eval_pop(sub, packed,
+                                              backend=self.eval_backend)
+                scores = out[:, :S].reshape(P, Cc, S)
         with obs.span("tnn.objective.score"):
-            acc = (np.argmax(scores, axis=2) == self.y[None, :]).mean(axis=1)
+            # argmax takes the first maximum: ties go to the lowest output
+            acc = (np.argmax(scores, axis=1) == self.y[None, :]).mean(axis=1)
             return np.stack([1.0 - acc, est], axis=1)
 
     def optimize(self, cfg: NSGA2Config) -> NSGA2Result:
