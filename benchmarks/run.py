@@ -1,4 +1,7 @@
-"""Benchmark harness — one module per paper table/figure.
+"""The paper's tables and figures — one module each.
+
+Speed is measured on the chip by `bench/` (see `BENCHMARK.json`), not
+here.
 
 Prints ``name,us_per_call,derived`` CSV rows: `name` is bench/row id,
 `us_per_call` the wall time of producing that row's experiment, `derived`
@@ -23,11 +26,6 @@ BENCH_MODULES = {
     "fig8": "benchmarks.fig8_nsga2",
     "table3": "benchmarks.table3_sota",
     "variation": "benchmarks.variation_robustness",
-    "roofline": "benchmarks.roofline_bench",
-    "cgp": "benchmarks.cgp_throughput",
-    "serve": "benchmarks.serve_throughput",
-    "evolve": "benchmarks.evolve_campaign",
-    "autopilot": "benchmarks.autopilot_loop",
 }
 BENCHES = list(BENCH_MODULES)
 

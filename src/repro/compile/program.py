@@ -45,9 +45,6 @@ class CircuitProgram:
     n_classes: int | None = None
     backend: str = "jax"
     devices: tuple | None = None
-    # Pallas word-tile width; forwarded to the kernel on the pallas
-    # backend, ignored elsewhere so configs can set it unconditionally
-    pallas_block_words: int | None = None
     _netlist: C.Netlist | None = field(default=None, repr=False)
     _jax_plan: tuple | None = field(default=None, repr=False)
 
@@ -133,8 +130,7 @@ class CircuitProgram:
         exec_backend = "swar" if self.backend == "jax" else self.backend
         out = D.program_eval_words(op, in0, in1, outs, words32,
                                    self.ir.n_inputs, backend=exec_backend,
-                                   devices=self.devices,
-                                   block_words=self.pallas_block_words)
+                                   devices=self.devices)
         return np.asarray(out[0], dtype=np.int64)
 
     # -- classifier inference ----------------------------------------------

@@ -14,10 +14,9 @@ exhaustive for n <= 16 inputs, Hamming-weight-stratified Monte-Carlo above
 Population-parallel fitness: all lambda children of a generation are scored
 in a single `NetlistPopulation` call (structure-of-arrays batched simulation
 + batched active-mask/area accounting), instead of a per-child Python loop —
-bit-identical results and trajectories, measured ~14x fitness evals/s at
-lambda=16 and ~7.5x end-to-end `evolve_popcount` wall-clock (n=8; see
-`benchmarks/cgp_throughput.py` / BENCH_cgp.json; `batch_eval=False` keeps
-the serial reference path).  `evolve_pc_library` additionally runs the
+bit-identical results and trajectories, measured on a CPU host at ~14x
+fitness evals/s at lambda=16 and ~7.5x end-to-end `evolve_popcount`
+wall-clock (n=8; `batch_eval=False` keeps the serial reference path).  `evolve_pc_library` additionally runs the
 independent tau-schedule points concurrently in a thread pool.
 
 Classic CGP efficiency trick: a mutation that touches only *inactive* genes

@@ -23,8 +23,8 @@ regardless of P (columns where the whole population agrees on the opcode
 take a cheaper direct path).  This is what makes CGP fitness evaluation
 population-parallel (see `core.cgp`): measured on this substrate the
 batched path is bit-identical to the per-child `Netlist.simulate` loop at
-~14x its evals/s for lambda=16 (n=8; 19-33x at lambda 32-64, ~14x at n=12,
-see `benchmarks/cgp_throughput.py` / BENCH_cgp.json).  `kernels.circuit_sim`
+~14x its evals/s for lambda=16 (n=8; 19-33x at lambda 32-64, ~14x at n=12;
+CPU host).  `kernels.circuit_sim`
 provides the jittable uint32-SWAR JAX twin for on-device fitness, another
 ~5-8x on top of the numpy path.
 """

@@ -35,31 +35,6 @@ from repro.core.circuits import NetlistPopulation
 BACKENDS = ("np", "swar", "pallas")
 
 
-def configure_worker_process(n_procs: int = 1) -> None:
-    """Cap math-library threading for a serve worker subprocess.
-
-    Must run *before* the first jax / BLAS import in the child: a fleet
-    spawning N worker processes on an M-core host wants each child's
-    intra-op thread pools sized ~M/N, not M — otherwise N children times
-    M threads oversubscribe the host and the per-dispatch latency the
-    deadline policy feeds on turns to noise.  `setdefault` keeps any
-    operator-provided caps; jax is left on its normal platform selection
-    (CPU on this container) and device counts are untouched, so worker
-    replicas still pin through `replica_devices` identically to in-process
-    ones.
-    """
-    import os
-
-    if n_procs < 1:
-        raise ValueError("n_procs must be >= 1")
-    cores = len(os.sched_getaffinity(0)) if hasattr(os, "sched_getaffinity") \
-        else (os.cpu_count() or 1)
-    per = str(max(1, cores // n_procs))
-    for var in ("OMP_NUM_THREADS", "OPENBLAS_NUM_THREADS",
-                "MKL_NUM_THREADS", "XLA_CPU_MULTI_THREAD_EIGEN_THREADS"):
-        os.environ.setdefault(var, per)
-
-
 def replica_devices(index: int, devices=None) -> tuple:
     """Round-robin device pin for serving-engine replica `index`.
 
@@ -88,20 +63,12 @@ def _device_slices(P: int, n_dev: int) -> list[slice]:
     return [slice(s, min(s + per, P)) for s in range(0, P, per)]
 
 
-def _pallas_kwargs(block_words) -> dict:
-    """Only non-default Pallas knobs, so jit static-arg caches stay warm."""
-    return {} if block_words is None else {"block_words": int(block_words)}
-
-
-def _device_eval_fn(backend: str, block_words):
+def _device_eval_fn(backend: str):
     """The device executor of `backend`.  The Pallas one opens its own
     plan, h2d and launch spans; the SWAR scan is one jitted launch."""
     if backend == "pallas":
-        from functools import partial
-
         from repro.kernels import pallas_circuit_sim as PS
-        return partial(PS.population_eval_uint,
-                       **_pallas_kwargs(block_words))
+        return PS.population_eval_uint
     from repro.kernels import circuit_sim as CS
 
     def launch(*args):
@@ -111,11 +78,11 @@ def _device_eval_fn(backend: str, block_words):
 
 
 def _eval_device(op, in0, in1, outputs, packed_u64, n_inputs, backend,
-                 devices, block_words=None) -> np.ndarray:
+                 devices) -> np.ndarray:
     import jax
 
     from repro.kernels import circuit_sim as CS
-    eval_fn = _device_eval_fn(backend, block_words)
+    eval_fn = _device_eval_fn(backend)
     with obs.span("dispatch.pack"):
         words32 = CS.pack_words32(packed_u64)
     per_individual = words32.ndim == 3
@@ -139,16 +106,12 @@ def _eval_device(op, in0, in1, outputs, packed_u64, n_inputs, backend,
 def population_eval_uint(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
                          outputs: np.ndarray, packed_u64: np.ndarray,
                          n_inputs: int, backend: str = "swar",
-                         devices=None, block_words=None) -> np.ndarray:
+                         devices=None) -> np.ndarray:
     """Per-vector decoded outputs `(P, S)` for a population of netlists.
 
     `packed_u64` is `(n_inputs, W)` shared or `(P, n_inputs, W)`
     per-individual uint64 words; every backend returns the same integers
     for the same words (rows are `Netlist.eval_uint` of the row's genome).
-
-    `block_words` is the Pallas word-tile width, forwarded to
-    `pallas_circuit_sim.population_eval_uint`; the other backends ignore
-    it, so campaign/tenant configs can set it unconditionally.
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown eval backend {backend!r}; "
@@ -163,23 +126,23 @@ def population_eval_uint(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
     return _eval_device(op32, np.asarray(in0, dtype=np.int32),
                         np.asarray(in1, dtype=np.int32),
                         np.asarray(outputs, dtype=np.int32),
-                        packed_u64, n_inputs, backend, devices,
-                        block_words=block_words).astype(np.int64)
+                        packed_u64, n_inputs, backend,
+                        devices).astype(np.int64)
 
 
 def population_eval_pop(pop: NetlistPopulation, packed_u64: np.ndarray,
-                        backend: str = "swar", devices=None,
-                        block_words=None) -> np.ndarray:
+                        backend: str = "swar",
+                        devices=None) -> np.ndarray:
     """`population_eval_uint` over an existing `NetlistPopulation`."""
     return population_eval_uint(pop.op, pop.in0, pop.in1, pop.outputs,
                                 packed_u64, pop.n_inputs, backend=backend,
-                                devices=devices, block_words=block_words)
+                                devices=devices)
 
 
 def program_eval_words(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
                        outputs: np.ndarray, words32: np.ndarray,
                        n_inputs: int, backend: str = "swar",
-                       devices=None, block_words=None) -> np.ndarray:
+                       devices=None) -> np.ndarray:
     """Single-program serving dispatch: `(n_inputs, W)` uint32 words ->
     `(P, W*32)` int64 decoded outputs, on any backend.
 
@@ -218,7 +181,7 @@ def program_eval_words(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
 
     import jax
 
-    eval_fn = _device_eval_fn(backend, block_words)
+    eval_fn = _device_eval_fn(backend)
     plan = (np.asarray(op, dtype=np.int32), np.asarray(in0, dtype=np.int32),
             np.asarray(in1, dtype=np.int32),
             np.asarray(outputs, dtype=np.int32))
@@ -234,8 +197,8 @@ def program_eval_words(op: np.ndarray, in0: np.ndarray, in1: np.ndarray,
         return np.asarray(out).astype(np.int64)
 
 
-def fleet_eval_words(plans: list, words_list: list, backend: str = "pallas",
-                     block_words=None) -> list[np.ndarray]:
+def fleet_eval_words(plans: list, words_list: list,
+                     backend: str = "pallas") -> list[np.ndarray]:
     """Whole-manifest serving dispatch: T tenants' circuits in ONE launch.
 
     `plans` holds one `(op, in0, in1, outputs, n_inputs)` plan tuple per
@@ -257,8 +220,7 @@ def fleet_eval_words(plans: list, words_list: list, backend: str = "pallas",
                          f"valid: {', '.join(BACKENDS)}")
     if backend == "pallas":
         from repro.kernels import pallas_circuit_sim as PS
-        outs = PS.fleet_eval_words(plans, words_list,
-                                   **_pallas_kwargs(block_words))
+        outs = PS.fleet_eval_words(plans, words_list)
         return [np.asarray(o, dtype=np.int64) for o in outs]
     outs = []
     for (op, in0, in1, outputs, n_in), w in zip(plans, words_list):

@@ -29,7 +29,8 @@ of packed test words:
   * the value plane is a `(n_inputs + G, bw)` int32 VMEM scratch; gate g
     reads rows `in0[g]`/`in1[g]` and writes row `n_inputs + g` with
     `pl.ds`, a dynamic *sublane* index, which Mosaic lowers;
-  * `bw` is either the whole word axis or a multiple of 128 lanes;
+  * `bw` is the whole word axis, or `DEFAULT_BLOCK_WORDS` (128 lanes)
+    when the axis is wider;
   * the decode writes a `(32, bw)` tile (row s = bit s of every word)
     and the jitted wrapper lays the tiles out as `(P, W*32)`.
 
@@ -68,7 +69,6 @@ from repro import obs
 from repro.kernels.circuit_sim import _C0_TBL, _CA_TBL, _CAB_TBL, _CB_TBL
 
 DEFAULT_BLOCK_WORDS = 128
-LANES = 128
 # kernel names, so a profile's device rows read the same after a refactor
 GATE_WALK = "tnn_gate_walk"         # one program (or a genome population)
 FLEET_WALK = "tnn_fleet_walk"       # the multi-program megakernel
@@ -85,13 +85,9 @@ def _interpret() -> bool:
                        f"interpreted on the CPU, not on {platform!r}")
 
 
-def _word_tile(W: int, block_words: int | None) -> int:
-    """Word-tile width: the whole word axis, or a multiple of 128 lanes."""
-    bw = DEFAULT_BLOCK_WORDS if block_words is None else int(block_words)
-    if bw <= 0 or bw % LANES:
-        raise ValueError(f"block_words must be a positive multiple of "
-                         f"{LANES}, got {block_words}")
-    return W if W <= bw else bw
+def _word_tile(W: int) -> int:
+    """Word-tile width: the whole word axis, or `DEFAULT_BLOCK_WORDS`."""
+    return min(W, DEFAULT_BLOCK_WORDS)
 
 
 def _kernel(plan_ref, outputs_ref, words_ref, out_ref, vals_ref, *,
@@ -192,15 +188,14 @@ def _plan_table(op, in0, in1) -> np.ndarray:
 
 
 def _run(op, in0, in1, outputs, words32, n_inputs: int, *,
-         block_words: int | None, decode: bool,
-         name: str = GATE_WALK) -> jax.Array:
+         decode: bool, name: str = GATE_WALK) -> jax.Array:
     with obs.span("dispatch.plan"):
         plan = _plan_table(op, in0, in1)
         outputs = np.asarray(outputs, dtype=np.int32)[:, None, :]
     with obs.span("dispatch.h2d"):
         words32 = jnp.asarray(words32, dtype=jnp.uint32)
         W = words32.shape[-1]
-        bw = _word_tile(W, block_words)
+        bw = _word_tile(W)
         wpad = (-W) % bw
         if wpad:
             pad_width = [(0, 0)] * (words32.ndim - 1) + [(0, wpad)]
@@ -212,8 +207,8 @@ def _run(op, in0, in1, outputs, words32, n_inputs: int, *,
                              interpret=_interpret(), name=name)
 
 
-def simulate_population(op, in0, in1, outputs, words32, n_inputs: int, *,
-                        block_words: int | None = None) -> jax.Array:
+def simulate_population(op, in0, in1, outputs, words32,
+                        n_inputs: int) -> jax.Array:
     """Pallas twin of `circuit_sim.simulate_population`.
 
     op/in0/in1: (P, G) int; outputs: (P, n_out) int; words32: (n_inputs, W)
@@ -227,13 +222,12 @@ def simulate_population(op, in0, in1, outputs, words32, n_inputs: int, *,
         # a zero-width word plane has nothing to simulate: no zero-size
         # grid or block may reach pallas_call
         return jnp.zeros((P, n_out, 0), dtype=jnp.uint32)
-    out = _run(op, in0, in1, outputs, words32, n_inputs,
-               block_words=block_words, decode=False)
+    out = _run(op, in0, in1, outputs, words32, n_inputs, decode=False)
     return out[:, :, :W]
 
 
-def fused_eval_uint(op, in0, in1, outputs, words32, n_inputs: int, *,
-                    block_words: int | None = None) -> jax.Array:
+def fused_eval_uint(op, in0, in1, outputs, words32,
+                    n_inputs: int) -> jax.Array:
     """Fused gate-walk + decode: `(P, W*32)` int32 in one `pallas_call`.
 
     Bit-identical to `circuit_sim.population_eval_uint` (and therefore to
@@ -244,8 +238,7 @@ def fused_eval_uint(op, in0, in1, outputs, words32, n_inputs: int, *,
     W = np.shape(words32)[-1]
     if W == 0:
         return jnp.zeros((P, 0), dtype=jnp.int32)
-    out = _run(op, in0, in1, outputs, words32, n_inputs,
-               block_words=block_words, decode=True)
+    out = _run(op, in0, in1, outputs, words32, n_inputs, decode=True)
     return out[:, : W * 32]
 
 
@@ -319,12 +312,12 @@ class FleetTable:
         return real, walked
 
 
-def fleet_table(plans, words: int, *, block_words: int | None = None,
+def fleet_table(plans, words: int, *,
                 buckets: tuple[int, ...] | None = None) -> FleetTable:
     """Pad `plans`, one `(op, in0, in1, outputs, n_inputs)` tuple a
     program (1-D or `(1, G)` rows), into a `FleetTable` on the default
-    device for word planes up to `words` wide, in word tiles of
-    `block_words` (see `_word_tile`).
+    device for word planes up to `words` wide, in the word tiles of
+    `_word_tile`.
 
     Gate nodes shift past the largest input count, every row gets the
     common gate budget (largest + 1: the last node is never written, so
@@ -374,7 +367,7 @@ def fleet_table(plans, words: int, *, block_words: int | None = None,
                       row_inputs=tuple(p[4] for p in norm) + (0,),
                       row_gates=tuple(int(g) for g in gates),
                       n_inputs=n_in_max, words=int(words),
-                      block_words=_word_tile(int(words), block_words),
+                      block_words=_word_tile(int(words)),
                       buckets=buckets or launch_buckets(N), device=device)
 
 
@@ -470,8 +463,7 @@ def warm_fleet_table(table: FleetTable) -> float:
     return dt
 
 
-def fleet_eval_words(plans, words_list, *,
-                     block_words: int | None = None) -> list[np.ndarray]:
+def fleet_eval_words(plans, words_list) -> list[np.ndarray]:
     """Evaluate T single-program circuits over T word planes in ONE launch.
 
     `plans` is a list of `(op, in0, in1, outputs, n_inputs)` tuples —
@@ -495,6 +487,5 @@ def fleet_eval_words(plans, words_list, *,
     W_max = max(w.shape[1] for w in words_list)
     if W_max == 0:
         return [np.zeros(0, dtype=np.int32) for _ in plans]
-    table = fleet_table(plans, W_max, block_words=block_words,
-                        buckets=(len(plans),))
+    table = fleet_table(plans, W_max, buckets=(len(plans),))
     return fleet_walk(table, range(len(plans)), words_list)

@@ -3,8 +3,7 @@
 `roofline.analysis` extracts terms from *compiled* XLA artifacts; the
 Pallas circuit kernels need the complementary view — a first-principles
 count of the word-ops and HBM bytes each variant moves for a given
-workload shape, so BENCH_evolve.json can show *why* the fused megakernel
-wins: not a faster gate loop, but orders of magnitude less traffic.
+workload shape, which shows *why* the fused megakernel wins: not a faster gate loop, but orders of magnitude less traffic.
 
 Workload: P programs x G gates x W uint32 words (32 test vectors per
 word), n_in packed input rows, n_out output taps.  Every gate applies the

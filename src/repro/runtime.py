@@ -23,7 +23,6 @@ from pathlib import Path
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
-DEVICE_BACKENDS = ("swar", "pallas", "jax")
 
 
 def enable_compile_cache() -> Path:

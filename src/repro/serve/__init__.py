@@ -7,13 +7,11 @@ One package now holds every serving layer: the batched execution engine
 queue-depth **admission control** and manifest **hot-reload**
 (`fleet.py` + `batcher.py`), the fleet controller — QoS classes,
 per-tenant token-bucket rate limits, and a hysteresis replica
-autoscaler (`autoscale.py`) — **process-per-backend dispatch workers**
-fed over shared-memory reading planes (`workers.py`), and a real
-network front: a length-prefixed binary wire protocol with
-version-negotiated batch frames (`protocol.py`), a sharded asyncio
-socket server with optional connectionless UDP ingest (`server.py`)
-and a blocking client library with batched submits and client-side
-coalescing (`client.py`).
+autoscaler (`autoscale.py`) — and a real network front: a
+length-prefixed binary wire protocol with version-negotiated batch
+frames (`protocol.py`), a sharded asyncio socket server with optional
+connectionless UDP ingest (`server.py`) and a blocking client library
+with batched submits and client-side coalescing (`client.py`).
 
 In-process:
 
@@ -61,7 +59,6 @@ from repro.serve.fleet import (
     TenantSpec,
 )
 from repro.serve.replicas import EngineReplica, ReplicaPool
-from repro.serve.workers import WorkerError, WorkerHost
 
 __all__ = [
     "DEFAULT_DEADLINE_MS",
@@ -84,6 +81,4 @@ __all__ = [
     "TenantSignals",
     "TenantSpec",
     "TokenBucket",
-    "WorkerError",
-    "WorkerHost",
 ]

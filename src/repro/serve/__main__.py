@@ -67,12 +67,6 @@ def _add_fleet_args(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--max-queue", type=int, default=None,
                     help="admission limit: shed submits beyond this queue "
                          "depth (default: never shed)")
-    ap.add_argument("--workers", type=int, default=None,
-                    help="process-per-backend dispatch workers: run N "
-                         "subprocesses per backend fed over shared-memory "
-                         "reading planes (default: dispatch in-process; "
-                         "refused for device backends on a TPU, whose "
-                         "chip belongs to one process)")
     ap.add_argument("--qos", default=None,
                     help="QoS classes: one of guaranteed|best_effort for "
                          "every tenant, or per-tenant pairs "
@@ -87,12 +81,8 @@ def _add_fleet_args(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--megakernel", action="store_true",
                     help="fused multi-tenant dispatch: every due pallas "
                          "tenant's circuit rides ONE multi-program kernel "
-                         "launch per scheduler pass (in-process only; "
-                         "non-pallas tenants dispatch normally)")
-    ap.add_argument("--block-words", type=int, default=None,
-                    help="pallas word-tile width override, a multiple of "
-                         "128 (per-tenant dispatch AND the fused "
-                         "megakernel launch)")
+                         "launch per scheduler pass (non-pallas tenants "
+                         "dispatch normally)")
     ap.add_argument("--autoscale", action="store_true",
                     help="grow/shrink replica pools from shed/queue/cost "
                          "pressure (bounds: --min-replicas/--max-replicas)")
@@ -223,13 +213,10 @@ def _build_fleet(args, live: bool = True) -> ClassifierFleet:
                                       float),
         min_replicas=getattr(args, "min_replicas", None),
         max_replicas=getattr(args, "max_replicas", None),
-        workers=(getattr(args, "workers", None) if live else None),
         best_effort_backlog=getattr(args, "best_effort_backlog", None),
         autoscale=autoscale,
         autoscale_interval_s=getattr(args, "autoscale_interval", 1.0),
         megakernel=(getattr(args, "megakernel", False) if live else False),
-        megakernel_block_words=getattr(args, "block_words", None),
-        pallas_block_words=getattr(args, "block_words", None),
         warmup=live, autostart=live)
 
 

@@ -42,15 +42,6 @@ pressure, dispatch-cost EMA — under round-based hysteresis with
 is the pure decision law; `autoscale_tick()` applies it and is safe to
 drive from a test with a fake clock).  Shadow tenants are never scaled.
 
-**Worker processes**: with `workers=N`, dispatch leaves this process —
-each backend gets N spawned subprocesses holding their own engines, fed
-through a ring of shared-memory reading planes (`serve/workers.py`).
-Scheduling, admission, stats and completion all stay here; only
-`classify_batch` crosses the process boundary, so np/swar/pallas
-dispatch runs on real cores instead of sharing this process's GIL.  On a
-TPU a chip belongs to one process, so device backends refuse worker
-processes there; serve them in-process with `replicas=N`.
-
 **Hot reload**: a fleet built by `from_emit_dir` can `sync_manifest()` at
 any time — new manifest rows become tenants, rows whose generation
 counter moved are replaced (queued requests transfer to the successor
@@ -86,7 +77,6 @@ from repro.serve.engine import (STATS_WINDOW, CircuitServingEngine,
                                 ServeStats, attach_labels)
 from repro.serve.replicas import EngineReplica, ReplicaPool, make_replica
 from repro.serve.shadow import ShadowComparator
-from repro.serve.workers import WorkerHost
 
 FLEET_BACKENDS = ("np", "swar", "pallas")
 DEFAULT_DEADLINE_MS = 50.0
@@ -254,8 +244,6 @@ class _Tenant:
         self.from_manifest = False      # sync_manifest may retire it
         self.shadow_of: str | None = None      # incumbent it mirrors, if any
         self.comparator: ShadowComparator | None = None
-        self.worker_key: str | None = None     # set when dispatch is
-                                               # delegated to a WorkerHost
         self._as_last_shed = 0          # autoscale_tick round deltas
         self._as_last_requests = 0
 
@@ -371,8 +359,6 @@ class _BackendWorker(threading.Thread):
                    if t.retiring and not len(t.batcher) and t.pool.idle()]
         if drained:
             self.tenants = [t for t in self.tenants if t not in drained]
-            for t in drained:       # free the worker procs' engines too
-                self.fleet._unload_worker_tenant(t)
             self.cond.notify_all()
 
     def _pick_jobs(self, now: float) -> list[_Tenant]:
@@ -450,24 +436,16 @@ class ClassifierFleet:
                  stats_window: int = STATS_WINDOW,
                  safety_factor: float = 1.5, sched_slack_s: float = 5e-3,
                  warmup: bool = True, autostart: bool = True,
-                 workers: int | None = None,
                  best_effort_backlog: int | None = None,
                  autoscale: AutoscaleConfig | None = None,
                  autoscale_interval_s: float = 1.0,
                  megakernel: bool = False,
-                 megakernel_block_words: int | None = None,
                  clock=time.perf_counter):
         if not specs:
             raise ValueError("a fleet needs at least one tenant")
         names = [s.name for s in specs]
         if len(set(names)) != len(names):
             raise ValueError(f"duplicate tenant names: {sorted(names)}")
-        if workers is not None and workers < 1:
-            raise ValueError("workers must be >= 1 (or None for in-process)")
-        if megakernel and workers is not None:
-            raise ValueError("megakernel dispatch is in-process (the fused "
-                             "launch pools every tenant's plan in one "
-                             "kernel) — it cannot ride worker subprocesses")
         self.stats = ServeStats(window=stats_window)
         self.stats_window = stats_window
         self.safety_factor = safety_factor
@@ -475,16 +453,12 @@ class ClassifierFleet:
         self.warmup_on_load = warmup
         self.best_effort_backlog = best_effort_backlog
         self._clock = clock
-        self.workers = workers
         self.megakernel = bool(megakernel)
-        self.megakernel_block_words = megakernel_block_words
         self._megakernel_launches = 0       # fused multi-tenant launches
         self._megakernel_peak_tenants = 0   # most tenants in one launch
         self._megakernel_tenants = 0        # tenants over all launches
         self._megakernel_gates = [0, 0]     # real, walked gate steps
         self._megakernel_lock = threading.Lock()
-        self._worker_hosts: dict[str, WorkerHost] = {}  # backend -> host
-        self._worker_key_seq = 0
         self._autoscaler = Autoscaler(autoscale) if autoscale else None
         self._autoscale_interval_s = autoscale_interval_s
         self._autoscale_stop = threading.Event()
@@ -515,38 +489,9 @@ class ClassifierFleet:
         if autostart:
             self.start()
 
-    def _ensure_host(self, backend: str) -> WorkerHost:
-        host = self._worker_hosts.get(backend)
-        if host is None:
-            host = WorkerHost(backend, self.workers)
-            host.start()
-            self._worker_hosts[backend] = host
-        return host
-
-    def _unload_worker_tenant(self, t: _Tenant) -> None:
-        """Drop a reaped tenant's engines from its worker procs, if any."""
-        if t.worker_key is None:
-            return
-        host = self._worker_hosts.get(t.spec.backend)
-        if host is not None:
-            host.unload(t.worker_key)
-
     def _build_tenant(self, spec: TenantSpec) -> _Tenant:
         t = _Tenant(spec, self.stats_window)
-        if self.workers is not None:
-            # dispatch runs out-of-process: broadcast the program to the
-            # backend's worker procs (each holds its own engine + jit
-            # cache) under a generation-unique key, so a replaced tenant's
-            # in-flight batches still hit the *old* program until reaped
-            host = self._ensure_host(spec.backend)
-            self._worker_key_seq += 1
-            t.worker_key = f"{spec.name}#{self._worker_key_seq}"
-            host.load(t.worker_key, spec.program, spec.max_batch)
-            if self.warmup_on_load:
-                est = max(1e-4, host.warmup(t.worker_key))
-                t.est_dispatch_s = est
-                t.last_dispatch_s = est
-        elif self.warmup_on_load and not self._fuses(spec):
+        if self.warmup_on_load and not self._fuses(spec):
             # every replica: each is pinned to its own device, so each has
             # its own executable to compile — a cold replica would pay jit
             # inside its first deadline-bound batch (a fused tenant's
@@ -574,8 +519,7 @@ class ClassifierFleet:
 
         plans = PS.fleet_table(
             [t.engine.program.plan() for t in tenants],
-            max(-(-t.spec.max_batch // 32) for t in tenants),
-            block_words=self.megakernel_block_words)
+            max(-(-t.spec.max_batch // 32) for t in tenants))
         if self.warmup_on_load:
             est = max(1e-4, PS.warm_fleet_table(plans))
             for t in fresh:
@@ -614,7 +558,6 @@ class ClassifierFleet:
                       rate_limit_rps: float | dict[str, float] | None = None,
                       min_replicas: int | None = None,
                       max_replicas: int | None = None,
-                      pallas_block_words: int | None = None,
                       **kw) -> "ClassifierFleet":
         """Serve every artifact the emit dir's `fleet.json` manifest names.
 
@@ -635,8 +578,7 @@ class ClassifierFleet:
                "tenants": tenants, "replicas": replicas,
                "max_queue": max_queue, "qos": qos,
                "rate_limit_rps": rate_limit_rps,
-               "min_replicas": min_replicas, "max_replicas": max_replicas,
-               "pallas_block_words": pallas_block_words}
+               "min_replicas": min_replicas, "max_replicas": max_replicas}
         doc = load_manifest_doc(emit_dir)
         rows = doc["tenants"]
         if tenants is not None:
@@ -667,13 +609,9 @@ class ClassifierFleet:
         # cross-check the bundle against the digest the row recorded: a
         # sidecar that agrees with its bundle can still disagree with the
         # manifest that promised it (stale emit, swapped file, tampered row)
-        program_kw = {}
-        if backend == "pallas" and ctx.get("pallas_block_words") is not None:
-            program_kw["pallas_block_words"] = int(ctx["pallas_block_words"])
         program = load_program(ctx["emit_dir"] / row["program"],
                                backend=backend,
-                               expect_sha256=row.get("sha256"),
-                               **program_kw)
+                               expect_sha256=row.get("sha256"))
         qos_ctx = ctx.get("qos")
         qos = (qos_ctx if isinstance(qos_ctx, str)
                else (qos_ctx or {}).get(row["name"],
@@ -1032,19 +970,11 @@ class ClassifierFleet:
         # SLO-accounted serving path, and a broken candidate must show up
         # in its comparator, not in the fleet's health signals
         is_shadow = tenant.shadow_of is not None
-        host = (self._worker_hosts.get(tenant.spec.backend)
-                if tenant.worker_key is not None else None)
         try:
             with obs.span("dispatch.gather"):
                 x = self._gather_batch(reqs)
-            # the dispatch timing deliberately includes the worker-path IPC
-            # (slab copy + queue round-trip): it is the cost the deadline
-            # policy must budget for, not just device time
             t0 = self._clock()
-            if host is not None:
-                labels = host.eval(tenant.worker_key, x)
-            else:
-                labels = replica.engine.classify_batch(x)
+            labels = replica.engine.classify_batch(x)
             dt = self._clock() - t0
         except Exception as exc:        # complete exceptionally, never hang
             msg = f"{type(exc).__name__}: {exc}"
@@ -1058,10 +988,6 @@ class ClassifierFleet:
             if not is_shadow:
                 self.stats.record(len(reqs), dt)
             tenant.stats.record(len(reqs), dt)
-            if host is not None:
-                # keep the replica-level batch ledger honest in worker
-                # mode too: timing/labels came from the worker proc
-                replica.engine.stats.record(len(reqs), dt)
             self._complete_requests(tenant, reqs, labels)
         return True
 
@@ -1476,10 +1402,7 @@ class ClassifierFleet:
         for i in range(k):
             rep = make_replica(t.spec.program, base + i, t.spec.max_batch,
                                stats_window=self.stats_window)
-            # in worker mode the subprocess engines are already warm; the
-            # fleet-side replica is only a concurrency token + ledger
-            if (self.warmup_on_load and t.worker_key is None
-                    and not self._fuses(t.spec)):
+            if self.warmup_on_load and not self._fuses(t.spec):
                 rep.engine.warmup()
             fresh.append(rep)
         with worker.cond:
@@ -1557,10 +1480,6 @@ class ClassifierFleet:
                 if w.is_alive():
                     raise TimeoutError(f"worker {w.name} did not stop "
                                        f"within {timeout}s")
-        # dispatch threads are parked; the worker procs have nothing in
-        # flight and can be torn down (slabs unlink here too)
-        for host in self._worker_hosts.values():
-            host.close()
 
     # -- observability -------------------------------------------------------
     def stats_summary(self) -> dict:
@@ -1636,11 +1555,7 @@ class ClassifierFleet:
                     "pad_share": 1.0 - real / walked if walked else 0.0,
                     "launch_shapes": (list(fused.plans.buckets)
                                       if fused is not None else []),
-                    "block_words": self.megakernel_block_words,
                 }
-        if self._worker_hosts:
-            out["workers"] = {b: h.summary()
-                              for b, h in sorted(self._worker_hosts.items())}
         if self._autoscaler is not None:
             out["autoscale"] = {**self._autoscaler.summary(),
                                 "events": self.autoscale_events[-16:]}

@@ -185,14 +185,12 @@ class FleetServer:
         return rows
 
     def _stats_doc(self) -> dict:
-        # stats_summary already carries the controller sections when armed:
-        # "workers" (per-backend subprocess hosts + slab ring) and
+        # stats_summary already carries the controller section when armed:
         # "autoscale" (round counter + recent scale events)
         doc = self.fleet.stats_summary()
         doc["transport"] = {
             "shards": self.shards,
             "n_connections": self.n_connections,
-            "worker_procs": self.fleet.workers,
             "udp": (dict(self.udp_stats)
                     if self.udp_address is not None else None),
         }

@@ -151,50 +151,40 @@ def test_zero_width_word_plane_returns_empty():
     assert fleet[0].shape == (0,)
 
 
-def test_block_words_knob_reaches_pallas_kernel(monkeypatch):
-    """Regression (PR 9): dispatch used to silently drop the Pallas knobs
-    — a campaign/tenant `block_words` override never reached the kernel.
-    Pin the plumbing end-to-end by spying on the jitted pallas_call
-    wrapper through `program_eval_words` AND `population_eval_uint`.
-    The word tile is the whole word axis or a multiple of 128 lanes, so
-    the plane here is 700 words wide and every override tiles it."""
+@pytest.mark.parametrize("W", (8, 128, 129, 700))
+def test_pallas_word_tiles_match_reference(monkeypatch, W):
+    """The word tile is the whole word axis up to 128 words and 128 past
+    it, the last tile zero-padded: a plane of one tile, exactly one, one
+    word over (127 pad words) and six tiles stay bit-identical to the
+    host reference through `program_eval_words` and
+    `population_eval_uint`."""
     from repro.kernels import dispatch as D
 
-    seen = []
+    tiles = []
     real = PS._fused_padded
 
-    def spy(*args, **kw):
-        seen.append(kw["block_words"])
-        return real(*args, **kw)
+    def spy(plan, outputs, words32, **kw):
+        tiles.append((kw["block_words"], words32.shape[-1]))
+        return real(plan, outputs, words32, **kw)
 
     monkeypatch.setattr(PS, "_fused_padded", spy)
     rng = np.random.default_rng(11)
     pop = C.random_netlist_population(rng, 5, 12, 2, 4)
-    bits = _rand_bits(rng, 700 * 32, 5)     # 700 words — default tile 128
+    S = W * 32
+    bits = _rand_bits(rng, S, 5)
+    ref = pop.eval_uint(C.pack_vectors(bits))[:, :S]
+
     words32 = np.asarray(CS.pack_bits32(bits))
-    ref = pop.eval_uint(C.pack_vectors(bits))
-
     got = D.program_eval_words(pop.op[:1], pop.in0[:1], pop.in1[:1],
-                               pop.outputs[:1], words32, 5,
-                               backend="pallas", block_words=256)
-    assert seen[-1] == 256, "block_words override never reached the kernel"
-    np.testing.assert_array_equal(got[0], ref[0])
-
+                               pop.outputs[:1], words32, 5, backend="pallas")
+    np.testing.assert_array_equal(got[0, :S], ref[0])
     got = D.population_eval_uint(pop.op, pop.in0, pop.in1, pop.outputs,
-                                 C.pack_vectors(bits), 5, backend="pallas",
-                                 block_words=384)
-    assert seen[-1] == 384
-    np.testing.assert_array_equal(got, ref)
+                                 C.pack_vectors(bits), 5, backend="pallas")
+    np.testing.assert_array_equal(got[:, :S], ref)
 
-    prog = CircuitProgram.from_netlist(pop.netlist(0), backend="pallas",
-                                       pallas_block_words=512)
-    prog.eval_bits(bits)
-    assert seen[-1] == 512, "CircuitProgram.pallas_block_words was dropped"
-
-    with pytest.raises(ValueError, match="multiple of 128"):
-        D.program_eval_words(pop.op[:1], pop.in0[:1], pop.in1[:1],
-                             pop.outputs[:1], words32, 5, backend="pallas",
-                             block_words=2)
+    bw = min(W, 128)
+    assert [t[0] for t in tiles] == [bw, bw]
+    assert all(wp % bw == 0 and wp >= W for _, wp in tiles)
 
 
 def test_np_backend_odd_width_repack_matches_swar():

@@ -75,8 +75,7 @@ def test_kernel_interprets_only_on_cpu(monkeypatch, on_platform, platform,
     rng = np.random.default_rng(3)
     pop = C.random_netlist_population(rng, 4, 6, 2, 2)
     words = np.ones((4, 3), dtype=np.uint32)
-    PS._run(pop.op, pop.in0, pop.in1, pop.outputs, words, 4,
-            block_words=None, decode=True)
+    PS._run(pop.op, pop.in0, pop.in1, pop.outputs, words, 4, decode=True)
     assert seen == [interpret]
 
 
@@ -106,40 +105,6 @@ def test_no_interpret_override_on_the_serving_path():
 
 
 # -- one process per chip ------------------------------------------------------
-def _toy_program(backend):
-    from repro.compile import CircuitProgram, lower_classifier
-    from repro.core import tnn as T
-
-    rng = np.random.default_rng(5)
-    w1t = rng.integers(-1, 2, size=(6, 3)).astype(np.int8)
-    w2t = T.balance_zero_counts(rng.normal(size=(3, 2)), 1 / 3)
-    tnn = T.TrainedTNN(w1t=w1t, w2t=w2t, thresholds=np.full(6, 0.5),
-                       train_acc=0.0, test_acc=0.0, name="toy")
-    cc = lower_classifier(tnn, *T.exact_netlists(tnn))
-    return CircuitProgram.from_classifier(cc, backend=backend)
-
-
-@pytest.mark.parametrize("backend", ("swar", "pallas"))
-def test_fleet_workers_refused_on_tpu(on_platform, backend):
-    from repro.serve import ClassifierFleet, TenantSpec, WorkerHost
-
-    on_platform("tpu")
-    with pytest.raises(RuntimeError, match=r"workers=None\) with replicas"):
-        WorkerHost(backend, 2)
-    spec = TenantSpec(name="toy", program=_toy_program(backend),
-                      backend=backend, max_batch=8)
-    with pytest.raises(RuntimeError, match="a chip belongs to one process"):
-        ClassifierFleet([spec], workers=2, warmup=False, autostart=False)
-
-
-def test_fleet_np_workers_allowed_on_tpu(on_platform):
-    from repro.serve import WorkerHost
-
-    on_platform("tpu")
-    host = WorkerHost("np", 1)          # host-only children: not refused
-    assert host.backend == "np"
-
-
 @pytest.mark.parametrize("platform", ("cpu", "tpu"))
 def test_island_executor_device_backend(on_platform, platform):
     from repro.evolve.config import CampaignConfig

@@ -291,12 +291,48 @@ def test_megakernel_only_fuses_pallas_backend(emit_dir):
         fleet.shutdown(drain=True)
 
 
-def test_megakernel_rejects_worker_processes(emit_dir):
+@pytest.mark.parametrize("replicas,megakernel",
+                         ((1, False), (2, False), (2, True)))
+def test_build_tenant_warms_every_replica(emit_dir, monkeypatch, replicas,
+                                          megakernel):
+    """Warm-up on load: each replica's engine compiles its batch shape and
+    the slowest warm dispatch seeds the tenant's estimate; a fused
+    tenant's launches warm with the fleet table instead, so none of its
+    engines warms."""
+    from repro.kernels import pallas_circuit_sim as PS
+    from repro.serve.engine import CircuitServingEngine
+
     out, _ = emit_dir
-    with pytest.raises(ValueError, match="megakernel"):
-        ClassifierFleet.from_emit_dir(out, backends="pallas",
-                                      megakernel=True, workers=2,
-                                      autostart=False, warmup=False)
+    warmed, table_s = [], []
+    real_engine, real_table = CircuitServingEngine.warmup, PS.warm_fleet_table
+
+    def engine_warmup(engine):
+        dt = real_engine(engine)
+        warmed.append((engine, dt))
+        return dt
+
+    def table_warmup(table):
+        table_s.append(real_table(table))
+        return table_s[-1]
+
+    monkeypatch.setattr(CircuitServingEngine, "warmup", engine_warmup)
+    monkeypatch.setattr(PS, "warm_fleet_table", table_warmup)
+    fleet = ClassifierFleet.from_emit_dir(
+        out, backends="pallas", tenants=["toy_a"], max_batch=32,
+        replicas=replicas, megakernel=megakernel, autostart=False)
+    try:
+        t = fleet._tenant("toy_a")
+        engines = [r.engine for r in t.pool.replicas]
+        assert len(engines) == replicas
+        if megakernel:
+            assert warmed == [] and len(table_s) == 1
+            want = max(1e-4, table_s[0])
+        else:
+            assert [e for e, _ in warmed] == engines and table_s == []
+            want = max([1e-4] + [dt for _, dt in warmed])
+        assert t.est_dispatch_s == t.last_dispatch_s == want > 0
+    finally:
+        fleet.shutdown()
 
 
 # ---------------------------------------------------------------------------

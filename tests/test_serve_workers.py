@@ -1,6 +1,6 @@
-"""Fleet-controller tier: QoS admission, token buckets, autoscaler, workers.
+"""Fleet-controller tier: QoS admission, token buckets, autoscaler.
 
-Four layers of pinning:
+Three layers of pinning:
 
   * **control law** — `TokenBucket` and `Autoscaler` are pure logic over
     injected clocks/round counters, so grow-after-sustained-pressure,
@@ -17,12 +17,6 @@ Four layers of pinning:
     driven manually (interval 0 ⇒ no background thread) grows a hot
     tenant to its ceiling and shrinks it back to the floor once drained,
     with no wall-clock dependence; shadows are never resized.
-  * **worker processes** — a `WorkerHost` serves labels bit-identical to
-    the offline `CircuitProgram.predict` over shared-memory planes,
-    propagates engine errors as `WorkerError`, and survives a killed
-    worker (pendings fail fast, the proc respawns with tenants intact);
-    a fleet in `workers=N` mode stays bit-identical through the full
-    scheduler path.
 
 Hypothesis drives protocol-v2 deadline tables (NaN / scalar / per-row
 mixes) end-to-end through encode → decode → `fleet.submit_many`,
@@ -40,7 +34,7 @@ from repro.compile import CircuitProgram, lower_classifier
 from repro.core import tnn as T
 from repro.serve import (AutoscaleConfig, Autoscaler, ClassifierFleet,
                          FleetOverloadError, MicroBatcher, TenantSignals,
-                         TenantSpec, TokenBucket, WorkerError, WorkerHost)
+                         TenantSpec, TokenBucket)
 from repro.serve import protocol as P
 
 N_EXAMPLES = int(os.environ.get("REPRO_CONFORMANCE_EXAMPLES", "20"))
@@ -421,59 +415,6 @@ def test_stats_summary_consistent_under_concurrent_sheds(prog):
         for th in threads:
             th.join(timeout=10.0)
         fleet.shutdown(drain=False)
-
-
-# ---------------------------------------------------------------------------
-# Worker processes: shared-memory dispatch, faults, respawn
-# ---------------------------------------------------------------------------
-def test_worker_host_bit_identity_errors_and_respawn(prog):
-    host = WorkerHost("np", 2, slab_bytes=1 << 16)
-    host.start()
-    try:
-        host.load("toy#1", prog, 32)
-        assert host.warmup("toy#1") > 0.0
-        rng = np.random.default_rng(3)
-        x = rng.normal(size=(24, F))
-        want = prog.predict(x)
-        np.testing.assert_array_equal(host.eval("toy#1", x), want)
-        with pytest.raises(WorkerError, match="not loaded"):
-            host.eval("nope#0", x)           # engine errors come back typed
-        # kill one worker: the proc respawns with its tenants reloaded and
-        # keeps answering bit-identically
-        host._procs[0].process.terminate()
-        host._procs[0].process.join(timeout=10.0)
-        deadline = time.monotonic() + 30.0
-        while host.n_respawns == 0 and time.monotonic() < deadline:
-            time.sleep(0.05)
-        assert host.n_respawns >= 1
-        for _ in range(4):                   # lands on both procs
-            np.testing.assert_array_equal(host.eval("toy#1", x), want)
-        s = host.summary()
-        assert s["n_evals"] >= 5 and s["tenants"] == ["toy#1"]
-        assert all(p["alive"] for p in s["procs"])
-        host.unload("toy#1")
-        assert host.summary()["tenants"] == []
-    finally:
-        host.close()
-
-
-def test_fleet_worker_mode_bit_identity(prog):
-    spec = _spec(prog, max_batch=16)
-    fleet = ClassifierFleet([spec], warmup=False, workers=1)
-    try:
-        rng = np.random.default_rng(5)
-        x = rng.normal(size=(40, F))
-        want = prog.predict(x)
-        reqs, shed, _ = fleet.submit_many("toy", x)
-        assert shed.size == 0
-        got = np.array([r.result(timeout=60.0) for r in reqs])
-        np.testing.assert_array_equal(got, want)
-        s = fleet.stats_summary()
-        assert s["workers"]["np"]["n_evals"] >= 1
-        assert s["workers"]["np"]["n_errors"] == 0
-        assert s["tenants"]["toy"]["n_slo_miss"] == 0
-    finally:
-        fleet.shutdown()
 
 
 # ---------------------------------------------------------------------------
