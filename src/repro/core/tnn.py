@@ -368,6 +368,25 @@ class TNNApproxProblem:
                              np.where(w2[:, o] == -1)[0]]) for o in cols],
             dtype=np.int64).reshape(len(cols), self.tnn.out_nnz)
         self._out_neg = (w2[self._out_idx, cols[:, None]] == -1).astype(np.uint8)
+        self.pack_rows()
+
+    def pack_rows(self) -> None:
+        """Pack the hidden-bit caches into the objective's uint64 words.
+
+        Packing a bit column commutes with the per-genome gather, so each
+        candidate's column is packed once here and `_objective` only
+        gathers words.  A NOT (w = -1) is an XOR with all-ones words
+        masked to the S real bits, so the tail bits stay 0 as
+        `pack_vectors` leaves them.  Call again after replacing the rows
+        (`fixed_hbits`, `hidden_bit_cache`).
+        """
+        S = self.fixed_hbits.shape[0]
+        self._hidden_words = [C.pack_vectors(cache.T)      # (n_cand, W)
+                              for cache in self.hidden_bit_cache]
+        self._fixed_words = C.pack_vectors(self.fixed_hbits)  # (H, W)
+        ones = C.pack_vectors(np.ones((S, 1), dtype=np.uint8))[0]
+        self._neg_words = np.where(self._out_neg[..., None] == 1, ones,
+                                   np.uint64(0))           # (C, nnz, W)
 
     # -- chromosome layout ---------------------------------------------------
     @property
@@ -421,11 +440,12 @@ class TNNApproxProblem:
     def objective(self, pop: np.ndarray) -> np.ndarray:
         """Population-parallel objectives: (N, n_genes) int -> (N, 2).
 
-        Hidden-gene bits come from the per-candidate caches via one gather;
-        every output neuron of every genome is scored in one launch a call:
-        the N x C candidate popcounts stack into one `NetlistPopulation` of
-        N·C rows (row n·C + o is genome n's neuron o) over per-row packed
-        inputs.  Matches `_eval_one` (the serial reference) bit-for-bit.
+        Hidden-gene words come from the per-candidate words packed once
+        (`pack_rows`) via one gather; every output neuron of every genome
+        is scored in one launch a call: the N x C candidate popcounts
+        stack into one `NetlistPopulation` of N·C rows (row n·C + o is
+        genome n's neuron o) over per-row packed inputs.  Matches
+        `_eval_one` (the serial reference) bit-for-bit.
         """
         pop = np.asarray(pop, dtype=np.int64)
         P = pop.shape[0]
@@ -440,9 +460,9 @@ class TNNApproxProblem:
         Cc, nnz = self._out_idx.shape
         with obs.span("tnn.objective.gather"):
             est = np.full(P, self.fixed_cost.area_mm2)
-            hbits = np.repeat(self.fixed_hbits[None], P, axis=0)  # (P, S, H)
-            for g, cache in enumerate(self.hidden_bit_cache):
-                hbits[:, :, self.hidden_idx[g]] = cache[pop[:, g]]
+            words = np.repeat(self._fixed_words[None], P, axis=0)  # (P, H, W)
+            for g, cand_words in enumerate(self._hidden_words):
+                words[:, self.hidden_idx[g]] = cand_words[pop[:, g]]
                 est = est + self._hidden_gene_areas[g][pop[:, g]]
             for o in range(Cc):
                 est = est + self._out_areas[pop[:, nh + o]]
@@ -451,8 +471,7 @@ class TNNApproxProblem:
             scores = np.zeros((P, Cc, S), dtype=np.int64)
         else:
             with obs.span("tnn.objective.pack"):
-                bits = hbits[:, :, self._out_idx] ^ self._out_neg  # (P,S,C,nnz)
-                packed = C.pack_vectors(bits.transpose(0, 2, 1, 3))
+                packed = words[:, self._out_idx] ^ self._neg_words  # (P,C,nnz,W)
                 packed = packed.reshape(P * Cc, nnz, -1)
             with obs.span("tnn.objective.eval"):
                 if self.eval_backend == "np":

@@ -234,8 +234,9 @@ def attach_tnn_drift(problem: CampaignProblem, rate: float,
     cheap, deterministic stand-in for "the sensor stream moved" that
     reuses the cached per-candidate bit planes (the caches are per-sample
     rows, so reindexing them *is* redrawing the data; nothing is
-    re-simulated).  Deterministic in `(seed, round)`: two controllers
-    replaying the same round sequence score identical objectives.
+    re-simulated, only the objective's words are repacked).
+    Deterministic in `(seed, round)`: two controllers replaying the same
+    round sequence score identical objectives.
     """
     if problem.approx is None:
         raise ValueError("only TNN problems carry a sample plane to drift")
@@ -258,6 +259,7 @@ def attach_tnn_drift(problem: CampaignProblem, rate: float,
         ap.hidden_bit_cache = [c[:, index_map] for c in orig_caches]
         ap.y = orig_y[index_map]
         ap.xbin = orig_xbin[index_map]
+        ap.pack_rows()
 
     problem.drift = drift
     return problem

@@ -7,6 +7,7 @@ from repro import obs
 from repro.core import circuits as C
 from repro.core import tnn as T
 from repro.core.pcc import PCCEntry, PCCLibrary
+from repro.evolve.problems import CampaignProblem, attach_tnn_drift
 
 # w2t (H=6, C=3): every column has two +1, two -1 and two zero weights,
 # at different rows, so the order of an output neuron's inputs matters
@@ -46,7 +47,8 @@ def _pcc_entries(p: int, n: int) -> list[PCCEntry]:
                      0.0, 0.0, 1.0) for a, b in pairs]
 
 
-def _problem(backend: str, w2t: np.ndarray = W2T) -> T.TNNApproxProblem:
+def _problem(backend: str, w2t: np.ndarray = W2T,
+             n_rows: int = S) -> T.TNNApproxProblem:
     tnn = T.TrainedTNN(w1t=W1T, w2t=w2t, thresholds=np.zeros(8),
                        train_acc=0.0, test_acc=0.0, name="toy")
     sizes = sorted({s for s in tnn.hidden_sizes() if s[0] >= 1 and s[1] >= 1})
@@ -59,8 +61,8 @@ def _problem(backend: str, w2t: np.ndarray = W2T) -> T.TNNApproxProblem:
     rng = np.random.default_rng(7)
     return T.TNNApproxProblem(
         tnn=tnn, pcc_lib=lib, pc_out_lib=outs,
-        xbin=(rng.random((S, 8)) < 0.5).astype(np.uint8),
-        y=rng.integers(0, w2t.shape[1], S), eval_backend=backend)
+        xbin=(rng.random((n_rows, 8)) < 0.5).astype(np.uint8),
+        y=rng.integers(0, w2t.shape[1], n_rows), eval_backend=backend)
 
 
 def _population(prob: T.TNNApproxProblem, P: int) -> np.ndarray:
@@ -106,3 +108,55 @@ def test_objective_without_output_inputs_launches_nothing():
     want = np.array([prob._eval_one(x) for x in pop])
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(got[:, 0], 1.0 - (prob.y == 0).mean())
+
+
+def _packed_by_bits(prob: T.TNNApproxProblem, pop: np.ndarray) -> np.ndarray:
+    """The launch's input planes packed from gathered uint8 bits: every
+    genome's (S, H) hidden bits, each output neuron's inputs through one
+    (C, nnz) gather, NOTs applied, then one `pack_vectors`."""
+    P = pop.shape[0]
+    hbits = np.repeat(prob.fixed_hbits[None], P, axis=0)
+    for g, cache in enumerate(prob.hidden_bit_cache):
+        hbits[:, :, prob.hidden_idx[g]] = cache[pop[:, g]]
+    bits = hbits[:, :, prob._out_idx] ^ prob._out_neg        # (P, S, C, nnz)
+    packed = C.pack_vectors(bits.transpose(0, 2, 1, 3))
+    return packed.reshape(P * prob._out_idx.shape[0], prob.tnn.out_nnz, -1)
+
+
+@pytest.mark.parametrize("signed", [True, False], ids=["not", "plain"])
+@pytest.mark.parametrize("P", [1, 5])
+@pytest.mark.parametrize("n_rows", [128, 100])
+def test_packed_planes_match_pack_vectors(monkeypatch, n_rows, P, signed):
+    w2t = W2T if signed else np.abs(W2T)
+    prob = _problem("np", w2t=w2t, n_rows=n_rows)
+    assert (prob._out_neg == 1).any() == signed
+    pop = _population(prob, P)
+    seen = []
+    eval_uint = C.NetlistPopulation.eval_uint
+
+    def spy(self, inputs):
+        seen.append(inputs.copy())
+        return eval_uint(self, inputs)
+
+    monkeypatch.setattr(C.NetlistPopulation, "eval_uint", spy)
+    prob.objective(pop)
+    assert len(seen) == 1
+    want = _packed_by_bits(prob, pop)
+    assert seen[0].dtype == want.dtype == np.uint64
+    assert seen[0].shape == want.shape == (P * 3, 4, -(-n_rows // 64))
+    np.testing.assert_array_equal(seen[0], want)     # tail bits included
+
+
+def test_objective_matches_eval_one_after_drift():
+    prob = _problem("np")
+    pop = _population(prob, 6)
+    cp = CampaignProblem(name="toy", domains=prob.domains(),
+                         objective=prob.objective, approx=prob)
+    attach_tnn_drift(cp, rate=0.5, seed=3)
+    y0 = prob.y.copy()
+    for r in (1, 2):
+        cp.drift(r)
+        got = prob.objective(pop)
+        want = np.array([prob._eval_one(x) for x in pop])
+        np.testing.assert_array_equal(got, want)
+    assert not np.array_equal(prob.y, y0)             # the rows did move
